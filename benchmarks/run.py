@@ -41,6 +41,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     import importlib
+
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     failures = 0
     for key, modname in MODULES:

@@ -516,7 +516,6 @@ class JaxProcessBackend(CollectiveBackend):
         hierarchical all-reduce schedule, for real.  With k > 1 the
         leading trainer axis is never reduced, so each group's row gets
         its own mean (non-member rows reduce their zeros to zeros)."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh, axes, group_axes = self._mesh, self._axes, self._group_axes
@@ -526,7 +525,7 @@ class JaxProcessBackend(CollectiveBackend):
                 x = jax.lax.pmean(x, ax)
             return x
 
-        return jax.jit(shard_map(mean_group, mesh=mesh,
+        return jax.jit(jax.shard_map(mean_group, mesh=mesh,
                                  in_specs=P(axes), out_specs=P(axes)))
 
     def _allsummer(self):
@@ -534,7 +533,6 @@ class JaxProcessBackend(CollectiveBackend):
         collective merges and the final consolidate ride.  Summing over
         the trainer axis too is what folds the groups' weighted
         replicas into one globally-replicated result."""
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         mesh, axes = self._mesh, self._axes
@@ -544,7 +542,7 @@ class JaxProcessBackend(CollectiveBackend):
                 x = jax.lax.psum(x, ax)
             return x
 
-        return jax.jit(shard_map(sum_all, mesh=mesh,
+        return jax.jit(jax.shard_map(sum_all, mesh=mesh,
                                  in_specs=P(axes), out_specs=P(axes)))
 
     def _ensure_jits(self):

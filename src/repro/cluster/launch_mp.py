@@ -11,6 +11,11 @@ deterministic event loop (pricing is pure float arithmetic on replicated
 state), computes only its own worker's inner steps, and meets the others
 inside the collectives; process 0 writes the report.
 
+Under ``JAX_PLATFORMS=cpu`` (tests, CI) each worker is a one-device CPU
+process and the collectives run over gloo.  Otherwise each worker owns
+one chip of this TPU host, and the parent itself never initializes JAX
+before its children have exited.
+
 The canonical workload is the same 16-dim quadratic the test-suite
 fixtures use (one trainer, M = nprocs workers, fixed batch), which is
 what makes the sim/real differential guarantee checkable:
@@ -87,6 +92,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
+
 #: toy-scale hardware constants shared with the bench/test fixtures so
 #: compute and comm land in comparable (simulated) regimes
 TOY = dict(flops=1e6, hbm_bw=1e9, link_bw=2e5, link_latency=2e-3)
@@ -153,7 +160,7 @@ def fixture(procs: int, *, rounds: int, pods: bool = False, seed: int = 0,
     if adaptive:
         acfg = dataclasses.replace(
             acfg, adaptive=True, stats_estimator="microbatch",
-            eta=0.25, max_batch=8, switch_multiplier=2,
+            max_batch=8, switch_multiplier=2,
             max_global_batch=64, k_correct=max(1, k_correct))
     prob = QuadraticProblem(dim=DIM, noise=2.0, seed=seed)
     inits = [{"x": jax.random.normal(jax.random.PRNGKey(seed + i), (DIM,))}
@@ -213,14 +220,46 @@ def run_sim(procs: int, *, rounds: int, policy: str = "sync",
 
 # --------------------------------------------------------------- worker
 
+def _on_cpu() -> bool:
+    """Whether the environment pins JAX to the CPU (``JAX_PLATFORMS=cpu``,
+    as the tests and CI do); otherwise the workers take the accelerator
+    JAX finds, one chip each."""
+    return os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+
+
+#: chips of one v5e host, as a 2x2 of processes with one chip each
+_TPU_HOST_CHIPS = 4
+
+
+def _tpu_process_env(procs: int) -> List[dict]:
+    """Per-rank libtpu settings that give each of ``procs`` processes
+    exactly one chip of this host, joined into one slice over the chips'
+    own interconnect (each process names its chip and the address every
+    process's slice builder listens on)."""
+    if procs != _TPU_HOST_CHIPS:
+        raise ValueError(f"one chip per process on a TPU host needs "
+                         f"--procs {_TPU_HOST_CHIPS}, not {procs}")
+    ports = [_free_port() for _ in range(procs)]
+    addresses = ",".join(f"localhost:{p}" for p in ports)
+    return [{"TPU_VISIBLE_CHIPS": str(rank),
+             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+             "TPU_PROCESS_BOUNDS": "2,2,1",
+             "TPU_PROCESS_ADDRESSES": addresses,
+             "TPU_PROCESS_PORT": str(ports[rank]),
+             "CLOUD_TPU_TASK_ID": str(rank)} for rank in range(procs)]
+
+
 def worker_main(args) -> int:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    cpu = _on_cpu()
+    if cpu:
+        # one device per process — the JaxProcessBackend mesh contract
+        os.environ.setdefault("XLA_FLAGS",
+                              "--xla_force_host_platform_device_count=1")
     import jax
-    try:
+    if cpu:
         # cross-process CPU collectives need a real transport
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:                    # older jaxlibs: single transport
-        pass
+    enable_compile_cache()
     jax.distributed.initialize(coordinator_address=args.coordinator,
                                num_processes=args.procs,
                                process_id=args.rank)
@@ -337,9 +376,7 @@ def run_mp(procs: int, *, rounds: int = 2, policy: str = "sync",
         os.path.abspath(__file__))))
     env = dict(os.environ)
     env["PYTHONPATH"] = src_root + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    # one device per process — the JaxProcessBackend mesh contract
-    env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=1")
+    rank_env = ([{}] * procs if _on_cpu() else _tpu_process_env(procs))
     children: List[subprocess.Popen] = []
     try:
         for rank in range(procs):
@@ -360,7 +397,7 @@ def run_mp(procs: int, *, rounds: int = 2, policy: str = "sync",
             elif trace or record_trace:
                 cmd.append("--record-trace")
             children.append(subprocess.Popen(
-                cmd, env=env, stdout=subprocess.PIPE,
+                cmd, env={**env, **rank_env[rank]}, stdout=subprocess.PIPE,
                 stderr=subprocess.STDOUT, text=True))
         deadline = time.time() + timeout
         tails = {}

@@ -311,9 +311,11 @@ class TrainerRound:
                 batch = stream.next_batch(plan.effective_batch)
                 batch = reshape_for_plan(batch, plan)
                 wp, opt_m, loss, grads = step_fn(wp, opt_m, batch)
+                # drop the replaced state now: held to the round's end it
+                # is one more optimizer state on the device
+                tr.inner_opt_states[m] = opt_m
             worker_params[m] = wp
             worker_grads.append(grads)
-            tr.inner_opt_states[m] = opt_m
             last_losses.append(float(loss))
 
         # ---- requested batch for the next round (Alg 3 line 31) ------
@@ -354,10 +356,8 @@ class TrainerRound:
                 # extra passes (DESIGN.md §3 — the grads come from
                 # slightly diverged worker params, an accepted
                 # approximation of the shared-point statistics)
-                stack = jax.tree.map(lambda *g: jnp.stack(g),
-                                     *worker_grads)
                 st = batching.stats_from_microbatch_grads(
-                    stack, plan.effective_batch,
+                    worker_grads, plan.effective_batch,
                     use_kernel=acfg.stats_use_kernel)
             else:
                 # the paper computes sigma_Bk / grad_Bk on the

@@ -48,6 +48,7 @@ BatchPlanProtocol``).
 from __future__ import annotations
 
 import math
+from functools import partial
 from typing import Callable, NamedTuple, Sequence
 
 import jax
@@ -80,12 +81,16 @@ def stats_from_matrix(G: jnp.ndarray, *, use_kernel: bool = False) -> GradStats:
                      jnp.maximum(ip_var, 0.0), jnp.maximum(orth_var, 0.0), b)
 
 
-def stats_from_microbatch_grads(grads_stack, micro_size: int, *,
+@partial(jax.jit, static_argnames=("micro_size", "use_kernel"))
+def stats_from_microbatch_grads(grads, micro_size: int, *,
                                 use_kernel: bool = False) -> GradStats:
-    """grads_stack: pytree with leading axis J of per-microbatch mean
-    grads (each over ``micro_size`` samples).  Rescales the variance
+    """grads: J pytrees of per-microbatch mean grads (each over
+    ``micro_size`` samples).  One program stacks and reduces them, so the
+    (J, D) matrix is the only gradient-sized copy it makes (eager ops
+    would add a stack and a squared copy: at 0.3B parameters those do
+    not fit one chip beside the trainer).  Rescales the variance
     estimates to per-sample units: Var(G_j) = σ²/m  =>  σ² = m·Var."""
-    G = flatten_grads(grads_stack)
+    G = flatten_grads(jax.tree.map(lambda *g: jnp.stack(g), *grads))
     st = stats_from_matrix(G, use_kernel=use_kernel)
     return rescale_microbatch(st, micro_size)
 

@@ -1,6 +1,9 @@
 """Blocked online-softmax (flash) attention Pallas kernel — TPU target.
 
 Design (TPU-native, not a CUDA port):
+  * q/k/v are laid out (batch, head, seq, hd) for the call, with batch
+    and head squeezed out of each block, so a block is a (rows, hd)
+    tile on the (sublane, lane) axes.
   * grid = (batch, q_head, Sq/BQ, Sk/BK); the last axis is sequential
     ("arbitrary" dimension semantics) and carries the online-softmax
     state (m, l, acc) in VMEM scratch.
@@ -40,9 +43,9 @@ def _flash_kernel(window_ref, q_ref, k_ref, v_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)          # (BQ, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)          # (BK, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)          # (BK, hd)
+    q = q_ref[...].astype(jnp.float32)                  # (BQ, hd)
+    k = k_ref[...].astype(jnp.float32)                  # (BK, hd)
+    v = v_ref[...].astype(jnp.float32)                  # (BK, hd)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -56,21 +59,21 @@ def _flash_kernel(window_ref, q_ref, k_ref, v_ref, o_ref,
     mask &= kpos > qpos - window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]                                 # (BQ,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+    m_prev = m_ref[...]                                 # (BQ, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     p = jnp.where(mask, p, 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
         l = l_ref[...]
-        o = acc_ref[...] / jnp.where(l > 0, l, 1.0)[:, None]
-        o_ref[0, :, 0, :] = o.astype(o_ref.dtype)
+        o = acc_ref[...] / jnp.where(l > 0, l, 1.0)
+        o_ref[...] = o.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
@@ -87,29 +90,35 @@ def flash_attention_padded(q, k, v, window, *, causal: bool = True,
     scale = 1.0 / math.sqrt(hd)
 
     grid = (B, H, nq, nk)
+    sq = pl.squeezed            # kernel sees (rows, hd) tiles
     kernel = functools.partial(_flash_kernel, bq=bq, bk=bk, scale=scale,
                                causal=causal)
-    return pl.pallas_call(
+    # heads move ahead of the sequence so each block's last two dims are
+    # (rows, hd): Mosaic tiles only the two minor dims, and a squeezed
+    # head dim there is refused
+    qt, kt, vt = (x.swapaxes(1, 2) for x in (q, k, v))
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((1, bq, 1, hd),
-                             lambda b, h, iq, ik, w: (b, iq, h, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, iq, ik, w: (b, ik, h // G, 0)),
-                pl.BlockSpec((1, bk, 1, hd),
-                             lambda b, h, iq, ik, w: (b, ik, h // G, 0)),
+                pl.BlockSpec((sq, sq, bq, hd),
+                             lambda b, h, iq, ik, w: (b, h, iq, 0)),
+                pl.BlockSpec((sq, sq, bk, hd),
+                             lambda b, h, iq, ik, w: (b, h // G, ik, 0)),
+                pl.BlockSpec((sq, sq, bk, hd),
+                             lambda b, h, iq, ik, w: (b, h // G, ik, 0)),
             ],
-            out_specs=pl.BlockSpec((1, bq, 1, hd),
-                                   lambda b, h, iq, ik, w: (b, iq, h, 0)),
+            out_specs=pl.BlockSpec((sq, sq, bq, hd),
+                                   lambda b, h, iq, ik, w: (b, h, iq, 0)),
             scratch_shapes=[
                 pltpu.VMEM((bq, hd), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
-                pltpu.VMEM((bq,), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
         interpret=interpret,
-    )(window, q, k, v)
+    )(window, qt, kt, vt)
+    return out.swapaxes(1, 2)

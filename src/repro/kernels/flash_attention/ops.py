@@ -1,12 +1,12 @@
-"""jit'd public wrapper: padding, window normalization, CPU interpret
-fallback.  Forward-only (serving / prefill); the training path uses the
+"""jit'd public wrapper: padding, window normalization, interpret mode
+on the CPU.  Forward-only (serving / prefill); the training path uses the
 XLA reference — Pallas kernels have no implicit VJP.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_padded
 from repro.models.layers import GLOBAL_WINDOW
 
@@ -32,7 +32,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         w = jnp.full((1,), GLOBAL_WINDOW, jnp.int32)
     else:
         w = jnp.asarray(window, jnp.int32).reshape(1)
-    interpret = jax.default_backend() == "cpu"
+    interpret = interpret_mode()
     out = flash_attention_padded(qp, kp, vp, w, causal=causal, bq=bq, bk=bk,
                                  interpret=interpret)
     return out[:, :S] if pad else out

@@ -12,10 +12,12 @@ B = probe batch).  The naive jnp formulation reads G three times
 so G streams HBM→VMEM exactly twice (once per pass) instead of three
 times, with f32 accumulators in VMEM.
 
-Layout: grid = (D/BD, B/BB) with the row axis sequential; each step
-loads a (BB, BD) tile.  BD = 512 lanes amortizes the per-tile overhead;
-accumulators: colsum (BD,), s/d (BB,) revisited across the D axis via
-output-block accumulation.
+Layout: every block is 2-D with a lane dim that is a multiple of 128, as
+Mosaic requires.  Pass 1: grid = (D/BD, B/BB), row axis sequential,
+colsum is a (1, D) row.  Pass 2: grid = (B/BB, D/BD), D axis sequential;
+s and d accumulate as (B, 128) per-lane partial sums (each (BB, BD) tile
+folds onto 128 lanes with aligned static slices), summed over the lanes
+outside the kernel.
 """
 from __future__ import annotations
 
@@ -25,8 +27,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
 
-def _colsum_kernel(g_ref, out_ref, *, bb: int):
+
+def _colsum_kernel(g_ref, out_ref):
     ib = pl.program_id(1)
 
     @pl.when(ib == 0)
@@ -34,7 +38,15 @@ def _colsum_kernel(g_ref, out_ref, *, bb: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     g = g_ref[...].astype(jnp.float32)                  # (BB, BD)
-    out_ref[...] += jnp.sum(g, axis=0)
+    out_ref[...] += jnp.sum(g, axis=0, keepdims=True)
+
+
+def _fold_lanes(x, bd: int):
+    """(BB, BD) -> (BB, 128): sum of the BD/128 lane-aligned slices."""
+    acc = x[:, :LANES]
+    for c in range(1, bd // LANES):
+        acc = acc + x[:, c * LANES:(c + 1) * LANES]
+    return acc
 
 
 def _moments_kernel(g_ref, gbar_ref, s_ref, d_ref, *, bd: int):
@@ -46,23 +58,23 @@ def _moments_kernel(g_ref, gbar_ref, s_ref, d_ref, *, bd: int):
         d_ref[...] = jnp.zeros_like(d_ref)
 
     g = g_ref[...].astype(jnp.float32)                  # (BB, BD)
-    gbar = gbar_ref[...].astype(jnp.float32)            # (BD,)
-    s_ref[...] += jnp.sum(g * g, axis=1)
-    d_ref[...] += g @ gbar
+    gbar = gbar_ref[...]                                # (1, BD) f32
+    s_ref[...] += _fold_lanes(g * g, bd)
+    d_ref[...] += _fold_lanes(g * gbar, bd)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "bd", "interpret"))
 def gradstats_padded(G, *, bb: int = 8, bd: int = 512,
                      interpret: bool = True):
-    """G: (B, D) with B % bb == 0, D % bd == 0.
+    """G: (B, D) with B % bb == 0, D % bd == 0, bd % 128 == 0.
     Returns (s (B,), d (B,), n2 (), b ())."""
     B, D = G.shape
     colsum = pl.pallas_call(
-        functools.partial(_colsum_kernel, bb=bb),
+        _colsum_kernel,
         grid=(D // bd, B // bb),
         in_specs=[pl.BlockSpec((bb, bd), lambda jd, ib: (ib, jd))],
-        out_specs=pl.BlockSpec((bd,), lambda jd, ib: (jd,)),
-        out_shape=jax.ShapeDtypeStruct((D,), jnp.float32),
+        out_specs=pl.BlockSpec((1, bd), lambda jd, ib: (0, jd)),
+        out_shape=jax.ShapeDtypeStruct((1, D), jnp.float32),
         interpret=interpret,
     )(G)
     gbar = colsum / B
@@ -71,17 +83,17 @@ def gradstats_padded(G, *, bb: int = 8, bd: int = 512,
         grid=(B // bb, D // bd),
         in_specs=[
             pl.BlockSpec((bb, bd), lambda ib, jd: (ib, jd)),
-            pl.BlockSpec((bd,), lambda ib, jd: (jd,)),
+            pl.BlockSpec((1, bd), lambda ib, jd: (0, jd)),
         ],
         out_specs=[
-            pl.BlockSpec((bb,), lambda ib, jd: (ib,)),
-            pl.BlockSpec((bb,), lambda ib, jd: (ib,)),
+            pl.BlockSpec((bb, LANES), lambda ib, jd: (ib, 0)),
+            pl.BlockSpec((bb, LANES), lambda ib, jd: (ib, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.float32),
+            jax.ShapeDtypeStruct((B, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((B, LANES), jnp.float32),
         ],
         interpret=interpret,
     )(G, gbar)
     n2 = jnp.sum(jnp.square(gbar))
-    return s, d, n2, jnp.float32(B)
+    return jnp.sum(s, axis=1), jnp.sum(d, axis=1), n2, jnp.float32(B)

@@ -22,6 +22,7 @@ from repro.configs import ARCH_REGISTRY, get_config, reduced
 from repro.configs.base import AdLoCoConfig
 from repro.core import train_adloco
 from repro.checkpoint import save_train_state
+from repro.compile_cache import enable_compile_cache
 from repro.data import make_shard_streams
 
 
@@ -60,6 +61,7 @@ def main(argv=None):
                          "before training")
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -396,3 +396,20 @@ def test_two_process_trace_digest_matches_sim(tmp_path):
     assert kinds.get("stats", 0) == 0
     assert kinds.get("compute", 0) > 0
     assert all(s.duration > 0.0 for s in reals)
+
+
+def test_tpu_workers_each_own_one_chip_of_one_slice():
+    """Off the CPU, run_mp gives rank r chip r and joins the four
+    one-chip processes into one 2x2 slice; other process counts do not
+    map onto a v5e host one chip each."""
+    envs = launch_mp._tpu_process_env(4)
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert {e["TPU_PROCESS_BOUNDS"] for e in envs} == {"2,2,1"}
+    assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for e in envs} == {"1,1,1"}
+    addresses = envs[0]["TPU_PROCESS_ADDRESSES"].split(",")
+    assert all(e["TPU_PROCESS_ADDRESSES"] == envs[0]["TPU_PROCESS_ADDRESSES"]
+               for e in envs)
+    assert [f"localhost:{e['TPU_PROCESS_PORT']}" for e in envs] == addresses
+    assert [e["CLOUD_TPU_TASK_ID"] for e in envs] == ["0", "1", "2", "3"]
+    with pytest.raises(ValueError, match="--procs"):
+        launch_mp._tpu_process_env(2)

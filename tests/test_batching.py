@@ -121,7 +121,8 @@ def test_microbatch_estimator_scaling():
     per_sample = rng.standard_normal((J * m, D)) * 3.0 + 1.0
     micro_means = per_sample.reshape(J, m, D).mean(1)
     st_micro = batching.stats_from_microbatch_grads(
-        {"g": jnp.asarray(micro_means, jnp.float32)}, micro_size=m)
+        [{"g": jnp.asarray(row, jnp.float32)} for row in micro_means],
+        micro_size=m)
     st_full = batching.stats_from_matrix(
         jnp.asarray(per_sample, jnp.float32))
     # rescaled micro sigma2 estimates the per-sample sigma2 (within 25%)

@@ -56,8 +56,8 @@ def test_distributed_stats_microbatch_rescale_matches_estimator():
     rng = np.random.default_rng(8)
     rows = [jnp.asarray(rng.standard_normal(24), jnp.float32)
             for _ in range(4)]
-    stack = {"g": jnp.stack(rows)}
-    st_in = batching.stats_from_microbatch_grads(stack, micro_size=8)
+    st_in = batching.stats_from_microbatch_grads([{"g": r} for r in rows],
+                                                 micro_size=8)
     # emulate 4 processes: each contributes one row, reduce = in-process
     # sums over the shard list
     shards = [r[None] for r in rows]

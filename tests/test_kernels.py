@@ -242,3 +242,18 @@ def test_stats_from_matrix_kernel_path_matches_ref_path(B, D, dtype):
         assert abs(float(x) - float(y)) <= \
             rel * max(abs(float(x)), abs(float(y))) + rel * scale, \
             (name, float(x), float(y))
+
+
+@pytest.mark.parametrize("platform,interpret", [("cpu", True),
+                                                ("tpu", False),
+                                                ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, platform, interpret):
+    """Interpret mode on the CPU, compiled kernels on the TPU, and an
+    error anywhere else: no platform silently falls back."""
+    from repro import kernels
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            kernels.interpret_mode()
+    else:
+        assert kernels.interpret_mode() is interpret

@@ -19,10 +19,13 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import (StepTraceAnnotation, TraceAnnotation,
+                          annotate_function)
 
 from repro import optim
 from repro.configs.base import AdLoCoConfig
@@ -167,6 +170,20 @@ class TrainerRound:
     ``outer`` applies the outer (pseudo-gradient) step to the trainer and
     meters the all-reduce.  Keeping the two phases separate is what lets
     the cluster runtime overlap them (ACCO-style async outer syncs).
+
+    Both phases mark their host work with ``jax.profiler`` spans, which a
+    profile places on the device's clock so an idle gap on the device
+    names the host work it fell in (and cost one cheap call each when no
+    profile runs):
+
+      adloco.inner         the compute phase
+        adloco.data        drawing and shaping one step's rows
+        adloco.step        dispatching one inner step
+        adloco.sync.loss   reading a worker's last loss (blocks the host)
+        adloco.stats       the batch decision (also ``apply_stats``)
+          adloco.sync.batch  reading the requested batch (blocks the host)
+      adloco.outer         the outer step
+        adloco.outer.stack stacking or reducing the workers' params
     """
 
     def __init__(self, loss_fn: Callable, acfg: AdLoCoConfig):
@@ -259,6 +276,7 @@ class TrainerRound:
         return self._n_params
 
     # --------------------------------------------------------- inner
+    @partial(annotate_function, name="adloco.inner")
     def inner(self, tr: TrainerState, *,
               fixed_batch: Optional[int] = None,
               worker_starts: Optional[List[Any]] = None,
@@ -308,81 +326,26 @@ class TrainerRound:
             opt_m = tr.inner_opt_states[m]
             stream = tr.streams[m % len(tr.streams)]
             for h in range(H):
-                batch = stream.next_batch(plan.effective_batch)
-                batch = reshape_for_plan(batch, plan)
-                wp, opt_m, loss, grads = step_fn(wp, opt_m, batch)
+                with TraceAnnotation("adloco.data", worker=m, step=h):
+                    batch = stream.next_batch(plan.effective_batch)
+                    batch = reshape_for_plan(batch, plan)
+                with TraceAnnotation("adloco.step", worker=m, step=h):
+                    wp, opt_m, loss, grads = step_fn(wp, opt_m, batch)
                 # drop the replaced state now: held to the round's end it
                 # is one more optimizer state on the device
                 tr.inner_opt_states[m] = opt_m
             worker_params[m] = wp
             worker_grads.append(grads)
-            last_losses.append(float(loss))
+            with TraceAnnotation("adloco.sync.loss", worker=m):
+                last_losses.append(float(loss))
 
         # ---- requested batch for the next round (Alg 3 line 31) ------
-        stats_bytes = 0.0
-        stats_request: Optional[Dict[str, Any]] = None
-        predicted = False
-        if acfg.adaptive and not self._is_correction(round_i):
-            # PadaDamp-style skipped round: read the fitted exponential
-            # trajectory instead of running the stats reduction — zero
-            # collectives, every rank fits the same observations so the
-            # shape-agreement contract holds without communication
-            tr.requested_batch = self._predictor_for(tr.tid).predict(
-                round_i, tr.requested_batch)
-            predicted = True
-        elif acfg.adaptive:
-            n = self._count_params(x_start)
-            if stats_reduce is not None:
-                # distributed backends: each process contributes its
-                # workers' microbatch-mean grads as shards of the exact
-                # two-phase composition; every rank receives identical
-                # reduced statistics, so the decision below agrees by
-                # construction (shape-agreement protocol)
-                G_local = batching.flatten_grads(
-                    jax.tree.map(lambda *g: jnp.stack(g), *worker_grads))
-                if defer_stats:
-                    st = None
-                    stats_request = {"phase1": self.protocol.begin(G_local),
-                                     "G_local": G_local,
-                                     "micro": plan.effective_batch}
-                else:
-                    st = self.protocol.reduce(
-                        G_local, stats_reduce,
-                        micro_size=plan.effective_batch)
-            elif acfg.stats_estimator == "microbatch" and len(idxs) >= 2:
-                # free distributed estimator: the M workers' last
-                # microbatch-mean grads are already materialized;
-                # Var over workers * m estimates sigma^2 with zero
-                # extra passes (DESIGN.md §3 — the grads come from
-                # slightly diverged worker params, an accepted
-                # approximation of the shared-point statistics)
-                st = batching.stats_from_microbatch_grads(
-                    worker_grads, plan.effective_batch,
-                    use_kernel=acfg.stats_use_kernel)
-            else:
-                # the paper computes sigma_Bk / grad_Bk on the
-                # CURRENT batch; stats_probe_size is only a memory
-                # cap (the E||g_B||^2 = ||g||^2 + sigma^2/B bias of
-                # a too-small probe stalls batch growth and breaks
-                # Theorem 2's ln-N communication profile)
-                probe_b = max(4, min(acfg.stats_probe_size,
-                                     plan.effective_batch))
-                probe = tr.streams[0].next_batch(probe_b)
-                st = batching.per_sample_stats(
-                    self.loss_fn, worker_params[idxs[0]], probe,
-                    use_kernel=acfg.stats_use_kernel)
-            if defer_stats:
-                # one-round-stale plan semantics: the decision folds at
-                # the outer sync's landing point (apply_stats), not here
-                if stats_request is None:
-                    stats_request = {"st": st}
-            else:
-                tr.requested_batch = self.protocol.decide(
-                    st, tr.requested_batch)
-                if acfg.k_correct > 1 and round_i is not None:
-                    self._predictor_for(tr.tid).observe(
-                        round_i, tr.requested_batch)
-            stats_bytes = self.protocol.payload_bytes(n)
+        stats_bytes, stats_request, predicted = 0.0, None, False
+        if acfg.adaptive:
+            stats_bytes, stats_request, predicted = self._decide_batch(
+                tr, plan, worker_grads, worker_params, idxs,
+                stats_reduce=stats_reduce, defer_stats=defer_stats,
+                round_i=round_i)
 
         spw = plan.effective_batch * H
         n = self._count_params(x_start)
@@ -399,7 +362,80 @@ class TrainerRound:
             stats_bytes=stats_bytes, stats_request=stats_request,
             predicted=predicted)
 
+    @partial(annotate_function, name="adloco.stats")
+    def _decide_batch(self, tr: TrainerState, plan: ExecutionPlan,
+                      worker_grads: List[Any], worker_params: List[Any],
+                      idxs: List[int], *, stats_reduce: Optional[Callable],
+                      defer_stats: bool, round_i: Optional[int]):
+        """The batch decision of an adaptive round (see :meth:`inner`):
+        sets ``tr.requested_batch``, or (``defer_stats``) returns the
+        stats handle that :meth:`apply_stats` folds later.  Returns
+        ``(stats_bytes, stats_request, predicted)``."""
+        acfg = self.acfg
+        if not self._is_correction(round_i):
+            # PadaDamp-style skipped round: read the fitted exponential
+            # trajectory instead of running the stats reduction — zero
+            # collectives, every rank fits the same observations so the
+            # shape-agreement contract holds without communication
+            tr.requested_batch = self._predictor_for(tr.tid).predict(
+                round_i, tr.requested_batch)
+            return 0.0, None, True
+        stats_request: Optional[Dict[str, Any]] = None
+        if stats_reduce is not None:
+            # distributed backends: each process contributes its
+            # workers' microbatch-mean grads as shards of the exact
+            # two-phase composition; every rank receives identical
+            # reduced statistics, so the decision below agrees by
+            # construction (shape-agreement protocol)
+            G_local = batching.flatten_grads(
+                jax.tree.map(lambda *g: jnp.stack(g), *worker_grads))
+            if defer_stats:
+                st = None
+                stats_request = {"phase1": self.protocol.begin(G_local),
+                                 "G_local": G_local,
+                                 "micro": plan.effective_batch}
+            else:
+                st = self.protocol.reduce(
+                    G_local, stats_reduce,
+                    micro_size=plan.effective_batch)
+        elif acfg.stats_estimator == "microbatch" and len(idxs) >= 2:
+            # free distributed estimator: the M workers' last
+            # microbatch-mean grads are already materialized;
+            # Var over workers * m estimates sigma^2 with zero
+            # extra passes (DESIGN.md §3 — the grads come from
+            # slightly diverged worker params, an accepted
+            # approximation of the shared-point statistics)
+            st = batching.stats_from_microbatch_grads(
+                worker_grads, plan.effective_batch,
+                use_kernel=acfg.stats_use_kernel)
+        else:
+            # the paper computes sigma_Bk / grad_Bk on the
+            # CURRENT batch; stats_probe_size is only a memory
+            # cap (the E||g_B||^2 = ||g||^2 + sigma^2/B bias of
+            # a too-small probe stalls batch growth and breaks
+            # Theorem 2's ln-N communication profile)
+            probe_b = max(4, min(acfg.stats_probe_size,
+                                 plan.effective_batch))
+            probe = tr.streams[0].next_batch(probe_b)
+            st = batching.per_sample_stats(
+                self.loss_fn, worker_params[idxs[0]], probe,
+                use_kernel=acfg.stats_use_kernel)
+        if defer_stats:
+            # one-round-stale plan semantics: the decision folds at
+            # the outer sync's landing point (apply_stats), not here
+            if stats_request is None:
+                stats_request = {"st": st}
+        else:
+            tr.requested_batch = self.protocol.decide(
+                st, tr.requested_batch)
+            if acfg.k_correct > 1 and round_i is not None:
+                self._predictor_for(tr.tid).observe(
+                    round_i, tr.requested_batch)
+        n = self._count_params(tr.params)
+        return self.protocol.payload_bytes(n), stats_request, False
+
     # ---------------------------------------------------- stale stats
+    @partial(annotate_function, name="adloco.stats")
     def apply_stats(self, tr: TrainerState, request: Dict[str, Any], *,
                     phase1_total=None, phase2_total=None,
                     sum_reduce: Optional[Callable] = None,
@@ -429,6 +465,7 @@ class TrainerRound:
         return tr.requested_batch
 
     # --------------------------------------------------------- outer
+    @partial(annotate_function, name="adloco.outer")
     def outer(self, tr: TrainerState, worker_params: List[Any], *,
               x_prev: Optional[Any] = None,
               comms: Optional[CommsMeter] = None, step: int = 0,
@@ -446,11 +483,12 @@ class TrainerRound:
         snapshot and this application); with ``delay_compensation`` on
         it damps the momentum contribution accordingly, otherwise it is
         ignored by the jitted step."""
-        if reduce is None:
-            stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                   *worker_params)
-        else:
-            stacked = reduce(worker_params)
+        with TraceAnnotation("adloco.outer.stack"):
+            if reduce is None:
+                stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                       *worker_params)
+            else:
+                stacked = reduce(worker_params)
         tr.params, tr.outer_opt_state = self.outer_step(
             x_prev if x_prev is not None else tr.params,
             stacked, tr.outer_opt_state, float(delay))
@@ -501,38 +539,40 @@ def train_adloco(loss_fn: Callable, init_params_list: List[Any],
     t0 = time.time()
 
     for t in range(1, T + 1):
-        # ---- CheckMerge / DoMerge (Alg 3 lines 11–16) ----------------
-        if (acfg.enable_merge and pool.k > 1
-                and t % acfg.merge_frequency == 0):
-            ids = check_merge([tr.requested_batch for tr in pool.trainers],
-                              acfg.merge_w + 1)  # w worst + representative
-            if len(ids) > 1:
-                pool = do_merge(pool, ids, step=t)
+        # one profiler step per round: the step view of a trace
+        with StepTraceAnnotation("adloco.round", step_num=t):
+            # ---- CheckMerge / DoMerge (Alg 3 lines 11–16) ----------------
+            if (acfg.enable_merge and pool.k > 1
+                    and t % acfg.merge_frequency == 0):
+                ids = check_merge([tr.requested_batch for tr in pool.trainers],
+                                  acfg.merge_w + 1)  # w worst + representative
+                if len(ids) > 1:
+                    pool = do_merge(pool, ids, step=t)
 
-        round_losses, modes = [], []
-        for tr in pool.trainers:
-            out = rnd.inner(tr, fixed_batch=fixed_batch, round_i=t)
-            round_losses.append(out.mean_loss)
-            modes.append(out.mode)
-            samples_total += out.samples
-            # ---- outer sync (Alg 3 lines 40–44) ----------------------
-            rnd.outer(tr, out.worker_params, comms=pool.comms, step=t)
+            round_losses, modes = [], []
+            for tr in pool.trainers:
+                out = rnd.inner(tr, fixed_batch=fixed_batch, round_i=t)
+                round_losses.append(out.mean_loss)
+                modes.append(out.mode)
+                samples_total += out.samples
+                # ---- outer sync (Alg 3 lines 40–44) ----------------------
+                rnd.outer(tr, out.worker_params, comms=pool.comms, step=t)
 
-        hist.outer_step.append(t)
-        hist.loss.append(sum(round_losses) / len(round_losses))
-        hist.pool_size.append(pool.k)
-        hist.requested_batches.append(
-            [tr.requested_batch for tr in pool.trainers])
-        hist.comm_events.append(pool.comms.events)
-        hist.comm_bytes.append(pool.comms.total_bytes)
-        hist.samples.append(samples_total)
-        hist.modes.append(modes)
-        hist.wall.append(time.time() - t0)
-        record_eval(hist, pool, eval_fn)
-        if verbose:
-            print(f"[adloco] t={t} loss={hist.loss[-1]:.4f} "
-                  f"k={pool.k} b={hist.requested_batches[-1]} "
-                  f"comm={pool.comms.events}")
+            hist.outer_step.append(t)
+            hist.loss.append(sum(round_losses) / len(round_losses))
+            hist.pool_size.append(pool.k)
+            hist.requested_batches.append(
+                [tr.requested_batch for tr in pool.trainers])
+            hist.comm_events.append(pool.comms.events)
+            hist.comm_bytes.append(pool.comms.total_bytes)
+            hist.samples.append(samples_total)
+            hist.modes.append(modes)
+            hist.wall.append(time.time() - t0)
+            record_eval(hist, pool, eval_fn)
+            if verbose:
+                print(f"[adloco] t={t} loss={hist.loss[-1]:.4f} "
+                      f"k={pool.k} b={hist.requested_batches[-1]} "
+                      f"comm={pool.comms.events}")
 
     pool = consolidate(pool, step=T)
     return pool, hist

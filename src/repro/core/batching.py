@@ -53,6 +53,7 @@ from typing import Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 
 class GradStats(NamedTuple):
@@ -292,7 +293,8 @@ def requested_batch(st: GradStats, acfg, current_b: int) -> int:
         b = augmented_test(st, acfg.theta, acfg.nu)
     else:
         raise ValueError(acfg.batch_test)
-    b = int(jax.device_get(b))
+    with TraceAnnotation("adloco.sync.batch"):
+        b = int(jax.device_get(b))      # the host waits for the test
     b = max(b, int(current_b))          # monotone non-decreasing
     return int(min(b, acfg.max_global_batch))
 

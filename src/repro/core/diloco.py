@@ -32,18 +32,22 @@ def make_inner_step_fn(loss_fn: Callable, inner_opt: optim.Optimizer,
     stay in param dtype — matters for the 314B configs' memory budget).
     """
 
-    def step_noaccum(params, opt_state, batch):
+    def inner_step(params, opt_state, batch):
         mb = jax.tree.map(lambda x: x[0], batch)
-        (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-            params, mb)
-        updates, opt_state = inner_opt.update(grads, opt_state, params)
-        params = optim.apply_updates(params, updates)
+        with jax.named_scope("grad"):
+            (loss, _), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+                params, mb)
+        with jax.named_scope("update"):
+            updates, opt_state = inner_opt.update(grads, opt_state, params)
+            params = optim.apply_updates(params, updates)
         return params, opt_state, loss, grads
 
-    def step(params, opt_state, batch):
+    def inner_step_accum(params, opt_state, batch):
         def micro_grad(carry, mb):
             g_acc, l_acc = carry
-            (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(params, mb)
+            with jax.named_scope("grad"):
+                (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(
+                    params, mb)
             g_acc = jax.tree.map(
                 lambda a, b: a + b.astype(jnp.float32), g_acc, g)
             return (g_acc, l_acc + loss), None
@@ -53,11 +57,12 @@ def make_inner_step_fn(loss_fn: Callable, inner_opt: optim.Optimizer,
                                          batch)
         inv = 1.0 / accum_steps
         grads = jax.tree.map(lambda g: g * inv, g_sum)
-        updates, opt_state = inner_opt.update(grads, opt_state, params)
-        params = optim.apply_updates(params, updates)
+        with jax.named_scope("update"):
+            updates, opt_state = inner_opt.update(grads, opt_state, params)
+            params = optim.apply_updates(params, updates)
         return params, opt_state, l_sum * inv, grads
 
-    return step_noaccum if accum_steps == 1 else step
+    return inner_step if accum_steps == 1 else inner_step_accum
 
 
 def make_inner_step(loss_fn: Callable, inner_opt: optim.Optimizer,
@@ -86,21 +91,23 @@ def make_outer_step(outer_opt: optim.Optimizer, *,
     ignored, keeping the plain path bit-identical to the legacy step.
     """
 
-    def step(x_prev, worker_params, outer_state, delay=0.0):
-        delta = jax.tree.map(
-            lambda xp, w: xp.astype(jnp.float32)
-            - jnp.mean(w.astype(jnp.float32), axis=0),
-            x_prev, worker_params)
-        if delay_aware:
-            updates, outer_state = outer_opt.update(
-                delta, outer_state, x_prev, delay=delay)
-        else:
-            updates, outer_state = outer_opt.update(delta, outer_state,
-                                                    x_prev)
-        x_new = optim.apply_updates(x_prev, updates)
+    def outer_step(x_prev, worker_params, outer_state, delay=0.0):
+        with jax.named_scope("grad"):
+            delta = jax.tree.map(
+                lambda xp, w: xp.astype(jnp.float32)
+                - jnp.mean(w.astype(jnp.float32), axis=0),
+                x_prev, worker_params)
+        with jax.named_scope("update"):
+            if delay_aware:
+                updates, outer_state = outer_opt.update(
+                    delta, outer_state, x_prev, delay=delay)
+            else:
+                updates, outer_state = outer_opt.update(delta, outer_state,
+                                                        x_prev)
+            x_new = optim.apply_updates(x_prev, updates)
         return x_new, outer_state
 
-    return jax.jit(step)
+    return jax.jit(outer_step)
 
 
 def merge_params(params_list, weights):

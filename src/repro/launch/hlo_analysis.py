@@ -300,12 +300,6 @@ class HloModule:
         return float(sum(self._shape_bytes(o) for o in ops)
                      + ins.result_bytes)
 
-    def _root_op(self, comp_name: str) -> Optional[Instr]:
-        for ins in self.computations.get(comp_name, []):
-            if "ROOT" in ins.line:
-                return ins
-        return None
-
     def _fusion_io_bytes(self, ins: Instr) -> float:
         """Fusion HBM traffic.  In-place dynamic-update-slice fusions
         alias their big input buffer: charge only the updated slice
@@ -375,72 +369,6 @@ class HloModule:
         return self.computation_cost(entry)
 
 
-    # ------------------------------------------------------ breakdown
-    def breakdown(self, top: int = 25):
-        """Attribute flops/bytes/collective bytes to individual
-        instructions (trip-count-scaled), for dry-run 'profiling'.
-
-        Returns (rows, loops): rows = list of dicts sorted by bytes desc;
-        loops = [(body_name, trips)] for every while encountered.
-        """
-        rows: Dict[Tuple[str, str], Dict[str, float]] = {}
-        loops: List[Tuple[str, int]] = []
-        entry = self._entry_name()
-        self._walk(entry, 1.0, rows, loops, set())
-        out = []
-        for (comp, op), v in rows.items():
-            out.append({"computation": comp, "op": op, **v})
-        out.sort(key=lambda r: -(r["bytes"] + r["collective_bytes"]))
-        return out[:top], loops
-
-    def _entry_name(self) -> str:
-        entry = None
-        for name in self.computations:
-            if name.startswith("main"):
-                entry = name
-        return entry or next(iter(self.computations))
-
-    def _walk(self, comp: str, scale: float, rows, loops, stack) -> None:
-        if comp in stack:       # cycle guard
-            return
-        stack = stack | {comp}
-        for ins in self.computations.get(comp, []):
-            if ins.op == "while":
-                calls = dict(re.findall(r"(body|condition)=%?([\w.\-]+)",
-                                        ins.line))
-                body, cond = calls.get("body"), calls.get("condition")
-                trips = self._trip_count(cond) if cond else 1
-                if body:
-                    loops.append((body, trips))
-                    self._walk(body, scale * trips, rows, loops, stack)
-                continue
-            if ins.op in ("conditional", "call", "async-start"):
-                for c in _CALLS.findall(ins.line):
-                    self._walk(c, scale, rows, loops, stack)
-            c = self._instr_cost(ins, top_level=True)
-            if c.flops or c.bytes or c.collective_bytes:
-                key = (comp, self._label(ins))
-                slot = rows.setdefault(key, {"flops": 0.0, "bytes": 0.0,
-                                             "collective_bytes": 0.0,
-                                             "count": 0.0})
-                slot["flops"] += c.flops * scale
-                slot["bytes"] += c.bytes * scale
-                slot["collective_bytes"] += c.collective_bytes * scale
-                slot["count"] += scale
-
-    def _label(self, ins: Instr) -> str:
-        """op kind + fusion-root kind + result shape, e.g.
-        'fusion/dynamic-update-slice f32[2,256,512,16]'."""
-        lab = ins.op
-        if ins.op == "fusion":
-            callees = _CALLS.findall(ins.line)
-            root = self._root_op(callees[0]) if callees else None
-            if root is not None:
-                lab += "/" + root.op
-        dims = ",".join(str(d) for d in ins.dims)
-        return f"{lab} {ins.dtype}[{dims}]"
-
-
 def analyze(hlo_text: str) -> dict:
     mod = HloModule(hlo_text)
     cost = mod.entry_cost()
@@ -451,24 +379,3 @@ def analyze(hlo_text: str) -> dict:
         "collective_wire_bytes": cost.collective_wire_bytes,
         "per_collective": cost.per_collective,
     }
-
-
-def profile(hlo_text: str, top: int = 25) -> str:
-    """Human-readable dry-run profile: top cost centers + loop structure."""
-    mod = HloModule(hlo_text)
-    rows, loops = mod.breakdown(top=top)
-    lines = ["=== while loops (body x trips) ==="]
-    seen = set()
-    for body, trips in loops:
-        if body not in seen:
-            seen.add(body)
-            lines.append(f"  {body:60s} x{trips}")
-    lines.append(f"=== top {top} cost centers (trip-scaled, per device) ===")
-    lines.append(f"{'bytes':>12s} {'coll_B':>12s} {'GFLOPs':>10s} "
-                 f"{'count':>8s}  where")
-    for r in rows:
-        lines.append(
-            f"{r['bytes']:12.3e} {r['collective_bytes']:12.3e} "
-            f"{r['flops'] / 1e9:10.1f} {r['count']:8.0f}  "
-            f"{r['computation'][:40]}::{r['op']}")
-    return "\n".join(lines)

@@ -222,9 +222,10 @@ class AdLoCoConfig:
     #   materializes (sigma^2 = m * Var(G_j)); zero extra forward/backward
     #   cost, requires M >= 2 (falls back to per_sample otherwise).
     stats_estimator: str = "per_sample"
-    # route the (B, D) stats reduction through the fused gradstats
-    # Pallas kernel instead of the pure-jnp oracle (same numbers to
-    # float tolerance; the kernel streams HBM twice instead of thrice)
+    # route the per-sample estimator's (B, D) stats reduction through
+    # the fused gradstats Pallas kernel instead of the pure-jnp oracle
+    # (same numbers to float tolerance; the kernel streams HBM twice
+    # instead of thrice)
     stats_use_kernel: bool = False
     inner_optimizer: str = "adamw"
     outer_optimizer: str = "nesterov"

@@ -406,8 +406,7 @@ class TrainerRound:
             # slightly diverged worker params, an accepted
             # approximation of the shared-point statistics)
             st = batching.stats_from_microbatch_grads(
-                worker_grads, plan.effective_batch,
-                use_kernel=acfg.stats_use_kernel)
+                worker_grads, plan.effective_batch)
         else:
             # the paper computes sigma_Bk / grad_Bk on the
             # CURRENT batch; stats_probe_size is only a memory

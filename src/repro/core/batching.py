@@ -23,7 +23,9 @@ Two estimator paths for the statistics:
 
 The fused single-pass reduction over the (B, D) gradient matrix is the
 ``gradstats`` Pallas kernel; ``repro.kernels.gradstats.ref`` is the
-pure-jnp oracle used here by default.
+pure-jnp oracle used here by default.  The microbatch estimator's J
+worker pytrees never form that matrix: :func:`stats_from_microbatch_grads`
+reduces them to their J x J Gram matrix, leaf by leaf.
 
 Distributed composition (the shape-agreement protocol)
 ------------------------------------------------------
@@ -73,6 +75,12 @@ def stats_from_matrix(G: jnp.ndarray, *, use_kernel: bool = False) -> GradStats:
     else:
         from repro.kernels.gradstats.ref import gradstats_reduce_ref
         s, d, gbar_n2, b = gradstats_reduce_ref(G)
+    return _stats_from_reductions(s, d, gbar_n2, b)
+
+
+def _stats_from_reductions(s, d, gbar_n2, b) -> GradStats:
+    """GradStats from per-row ``s_i = ||g_i||²``, ``d_i = <g_i, ḡ>``,
+    ``||ḡ||²`` and the f32 row count ``b``."""
     bm1 = jnp.maximum(b - 1.0, 1.0)
     sigma2 = (jnp.sum(s) - b * gbar_n2) / bm1
     ip_var = jnp.sum(jnp.square(d - gbar_n2)) / bm1
@@ -82,17 +90,25 @@ def stats_from_matrix(G: jnp.ndarray, *, use_kernel: bool = False) -> GradStats:
                      jnp.maximum(ip_var, 0.0), jnp.maximum(orth_var, 0.0), b)
 
 
-@partial(jax.jit, static_argnames=("micro_size", "use_kernel"))
-def stats_from_microbatch_grads(grads, micro_size: int, *,
-                                use_kernel: bool = False) -> GradStats:
+@partial(jax.jit, static_argnames=("micro_size",))
+def stats_from_microbatch_grads(grads, micro_size: int) -> GradStats:
     """grads: J pytrees of per-microbatch mean grads (each over
-    ``micro_size`` samples).  One program stacks and reduces them, so the
-    (J, D) matrix is the only gradient-sized copy it makes (eager ops
-    would add a stack and a squared copy: at 0.3B parameters those do
-    not fit one chip beside the trainer).  Rescales the variance
-    estimates to per-sample units: Var(G_j) = σ²/m  =>  σ² = m·Var."""
-    G = flatten_grads(jax.tree.map(lambda *g: jnp.stack(g), *grads))
-    st = stats_from_matrix(G, use_kernel=use_kernel)
+    ``micro_size`` samples).  One program reads each gradient once and
+    makes no gradient-sized copy: every statistic is a function of the
+    J x J Gram matrix C[j, k] = <g_j, g_k>, accumulated leaf by leaf in
+    f32 (s_j = C[j, j], d_j = mean_k C[j, k], ||ḡ||² = mean C).  Each
+    leaf's J² products and sums fuse into one pass over its J copies.
+    They are elementwise f32 products, not a matmul, so no default
+    matmul precision rounds them.  Rescales the variance estimates to
+    per-sample units: Var(G_j) = σ²/m  =>  σ² = m·Var."""
+    J = len(grads)
+    C = jnp.zeros((J, J), jnp.float32)
+    for leaves in zip(*(jax.tree.leaves(g) for g in grads)):
+        g = [leaf.astype(jnp.float32) for leaf in leaves]
+        C = C + jnp.stack([jnp.stack([jnp.sum(a * b) for b in g])
+                           for a in g])
+    st = _stats_from_reductions(jnp.diagonal(C), jnp.mean(C, axis=1),
+                                jnp.mean(C), jnp.float32(J))
     return rescale_microbatch(st, micro_size)
 
 

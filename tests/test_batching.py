@@ -130,6 +130,59 @@ def test_microbatch_estimator_scaling():
         float(st_full.sigma2), rel=0.25)
 
 
+def _worker_grads(seed, J, dtype):
+    """J gradient pytrees that share a mean direction, as workers' do:
+    an embedding, a stacked (L, ...) layer leaf with its norms, a bias."""
+    rng = np.random.default_rng(seed)
+    shapes = {"embed": (40, 16), "layers": {"w": (3, 8, 12), "norm": (3, 8)},
+              "bias": (7,)}
+    noise = rng.uniform(0.2, 3.0)
+    mean = jax.tree.map(lambda s: rng.standard_normal(s), shapes,
+                        is_leaf=lambda s: isinstance(s, tuple))
+    return [jax.tree.map(
+        lambda m: jnp.asarray(m + noise * rng.standard_normal(m.shape),
+                              dtype), mean) for _ in range(J)]
+
+
+@jax.jit
+def _matrix_route(grads, micro_size):
+    G = batching.flatten_grads(jax.tree.map(lambda *g: jnp.stack(g), *grads))
+    return batching.rescale_microbatch(batching.stats_from_matrix(G),
+                                       micro_size)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("J", [2, 3, 5])
+def test_microbatch_gram_route_matches_matrix_route(J, dtype):
+    """The Gram-matrix statistics agree with the (J, D) matrix route on
+    pytrees of several leaves, a stacked layer leaf among them."""
+    grads = _worker_grads(J, J, dtype)
+    got = batching.stats_from_microbatch_grads(grads, 4)
+    want = _matrix_route(grads, 4)
+    for name, rel in [("mean_norm2", 1e-5), ("sigma2", 1e-5),
+                      ("orth_var", 1e-5), ("ip_var", 1e-4)]:
+        assert float(getattr(got, name)) == pytest.approx(
+            float(getattr(want, name)), rel=rel), name
+    assert float(got.b) == J
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("test", ["norm", "inner_product", "augmented"])
+@pytest.mark.parametrize("J", [2, 3, 5])
+def test_microbatch_gram_route_requests_the_same_batch(J, test, dtype):
+    """Both routes give the same requested batch on 50 seeds."""
+    acfg = AdLoCoConfig(batch_test=test, eta=0.5, theta=0.01, nu=0.5,
+                        max_global_batch=1 << 30)
+    for seed in range(50):
+        grads = _worker_grads(seed, J, dtype)
+        got = batching.stats_from_microbatch_grads(grads, 4)
+        want = _matrix_route(grads, 4)
+        assert batching.requested_batch(got, acfg, 1) == \
+            batching.requested_batch(want, acfg, 1), seed
+
+
 @settings(max_examples=25, deadline=None)
 @given(st.integers(2, 40), st.integers(1, 96), st.integers(0, 2 ** 31 - 1))
 def test_property_stats_nonnegative_any_matrix(b, dim, seed):

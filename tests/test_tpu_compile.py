@@ -138,11 +138,10 @@ def test_smoke_inner_step_fits_one_v5e(one_chip, b_req):
     assert in_flight + resident < HBM_BYTES, (in_flight, resident)
 
 
-def test_smoke_microbatch_stats_fit_one_v5e(one_chip):
+def _smoke_stats_compiled(one_chip):
     """The batch statistics after an accum round of ``chip_smoke.py``
-    (two workers' f32 gradients) compile as one program that fits beside
-    the trainer's state: params, f32 outer momentum, two AdamW states
-    and two workers' params."""
+    (two workers' f32 gradients), compiled for one v5e, and the
+    parameter shapes."""
     from repro.core import batching
 
     cfg = get_config(chip_smoke.ARCH).with_overrides(
@@ -152,11 +151,36 @@ def test_smoke_microbatch_stats_fit_one_v5e(one_chip):
     grads = _shapes(jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, jnp.float32), params),
         one_chip)
-    compiled = batching.stats_from_microbatch_grads.lower(
-        [grads, grads], micro_size=chip_smoke.SMOKE["max_batch"]).compile()
+    return batching.stats_from_microbatch_grads.lower(
+        [grads, grads], micro_size=chip_smoke.SMOKE["max_batch"]
+    ).compile(), params
+
+
+def test_smoke_microbatch_stats_fit_one_v5e(one_chip):
+    """The batch statistics after an accum round of ``chip_smoke.py``
+    (two workers' f32 gradients) compile as one program that fits beside
+    the trainer's state: params, f32 outer momentum, two AdamW states
+    and two workers' params."""
+    compiled, params = _smoke_stats_compiled(one_chip)
     mem = compiled.memory_analysis()
     stats_bytes = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                    + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     n = sum(a.size for a in jax.tree.leaves(params))
     resident = n * (2 + 4 + 2 * 8 + 2 * 2)
     assert stats_bytes + resident < HBM_BYTES, (stats_bytes, resident)
+
+
+def test_smoke_microbatch_stats_read_each_gradient_once(one_chip):
+    """The batch statistics of ``chip_smoke.py``'s two workers' f32
+    gradients make no gradient-sized copy and read each tree once:
+    temporaries under 1/16 of the (2, D) f32 matrix, bytes accessed
+    under 1.5x one read of the two trees (a second pass over them
+    would read 2x)."""
+    compiled, params = _smoke_stats_compiled(one_chip)
+    two_trees = 2 * 4 * sum(a.size for a in jax.tree.leaves(params))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < two_trees / 16, (temp, two_trees)
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    accessed = cost["bytes accessed"]
+    assert accessed < 1.5 * two_trees, (accessed, two_trees)

@@ -65,7 +65,7 @@ FAULTS = {"state_unchanged": state_unchanged,
 
 
 def sound_or_fault(harness, cell, seed, fault=None):
-    norms = harness.Norms()
+    norms = harness.Norms(cell.family)
     prog = harness.build_and_first_round(
         cell, seed, harness.rows_for(cell, 0.0, 0.0), norms, fault)
     readings, feeds = prog.readings, prog.feeds

@@ -1,20 +1,12 @@
-"""Operations and bytes of the programs the window drives, from shapes,
-and the table of the chip's peaks (``peaks.json``, keyed by the
-``device_kind`` JAX reports).
-
-Model FLOPs per token of a training step are 6 per matmul parameter
-(forward, and the two matmuls of the backward pass) plus 12·L·q_dim·S
-for the attention scores and their weighted sum over the whole S×S
-matrix, as the program computes it.  The embedding lookup is no matmul
-and does not count; the output head does.  Recomputation under remat
-does not count.
+"""The least HBM bytes of an inner step, from the parameter count, and
+the table of the chip's peaks (``peaks.json``, keyed by the
+``device_kind`` JAX reports).  Each family counts its own model FLOPs
+(``train_flops_per_token``) and parameters (``param_count``).
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
-
-from benchmarks.chip.reference import Arch
 
 PEAKS = Path(__file__).with_name("peaks.json")
 
@@ -32,33 +24,13 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def matmul_params(a: Arch) -> int:
-    d, hd = a.d_model, a.head_dim
-    qd, kvd = a.num_heads * hd, a.num_kv_heads * hd
-    per_layer = d * qd + 2 * d * kvd + qd * d + 3 * d * a.d_ff
-    return a.num_layers * per_layer + d * a.vocab_size
-
-
-def param_count(a: Arch) -> int:
-    d = a.d_model
-    norms = a.num_layers * (2 * d + (2 * a.head_dim if a.qk_norm else 0)) + d
-    # a tied head is the embedding, already counted among the matmuls
-    embed = 0 if a.tied else d * a.vocab_size
-    return matmul_params(a) + norms + embed
-
-
-def train_flops_per_token(a: Arch, seq_len: int) -> float:
-    qd = a.num_heads * a.head_dim
-    return 6.0 * matmul_params(a) + 12.0 * a.num_layers * qd * seq_len
-
-
-def step_bytes(a: Arch, *, accum: bool) -> float:
+def step_bytes(params: int, *, accum: bool) -> float:
     """Least HBM traffic of one inner step with bfloat16 parameters: read
     the parameters, write them back, read and write AdamW's two float32
     moments, and write the gradient (float32 when accumulated, else
     bfloat16)."""
     grad = 4 if accum else 2
-    return float(param_count(a) * (2 * 2 + 4 * 4 + grad))
+    return float(params * (2 * 2 + 4 * 4 + grad))
 
 
 def share(value: float, name: str) -> float:

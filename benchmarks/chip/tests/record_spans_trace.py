@@ -78,8 +78,9 @@ def main(argv=None) -> int:
     from benchmarks.chip.tests.tiny import tiny_cell
 
     harness.require_chips(jax, 1)
-    prog = harness.build_and_first_round(tiny_cell(CELL), SEED, 64,
-                                         harness.Norms())
+    cell = tiny_cell(CELL)
+    prog = harness.build_and_first_round(cell, SEED, 64,
+                                         harness.Norms(cell.family))
     log_dir = tempfile.mkdtemp(prefix="spans-trace-")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0

@@ -6,25 +6,26 @@ from benchmarks.chip import flops as F
 from benchmarks.chip import harness
 
 
-def arch(name):
-    return harness.arch_of(harness.load_cell(name).config)
+def family_and_arch(name):
+    cell = harness.load_cell(name)
+    return cell.family, cell.family.arch_of(cell.config)
 
 
 @pytest.mark.parametrize("cell,gflop,params", [
     ("qwen3-0.6b.switch-2x4", 1.8898, 281_431_040),
     ("stablelm-1.6b.switch-2x4", 1.3086, 256_915_456)])
 def test_flops_and_params_per_configuration(cell, gflop, params):
-    a = arch(cell)
-    assert F.train_flops_per_token(a, 1024) / 1e9 == pytest.approx(gflop,
-                                                                   abs=1e-4)
-    assert F.param_count(a) == params
-    cfg = harness.program_config(harness.load_cell(cell).config)
+    fam, a = family_and_arch(cell)
+    assert fam.train_flops_per_token(a, 1024) / 1e9 == pytest.approx(
+        gflop, abs=1e-4)
+    assert fam.param_count(a) == params
+    cfg = fam.program_config(harness.load_cell(cell).config)
     assert cfg.param_count() == params
 
 
 def test_qwen_head_is_over_half_the_matmuls():
-    a = arch("qwen3-0.6b.switch-2x4")
-    assert a.d_model * a.vocab_size / F.matmul_params(a) > 0.55
+    fam, a = family_and_arch("qwen3-0.6b.switch-2x4")
+    assert a.d_model * a.vocab_size / fam.matmul_params(a) > 0.55
 
 
 def test_unknown_device_kind_raises():

@@ -8,6 +8,7 @@ import shutil
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 from benchmarks.chip import calibrate, harness
@@ -21,7 +22,8 @@ SEED = 2 ** 33 + 12345          # wider than 32 bits, as run seeds may be
 @pytest.fixture(scope="module", params=CELLS)
 def sound(request):
     cell = tiny_cell(request.param)
-    prog = harness.build_and_first_round(cell, SEED, 64, harness.Norms())
+    prog = harness.build_and_first_round(cell, SEED, 64,
+                                         harness.Norms(cell.family))
     win = harness.run_window(prog, 0.5)
     ref = harness.reference_readings(cell, SEED, prog.feeds)
     return cell, prog, win, harness.compare(prog.readings, ref)
@@ -97,3 +99,17 @@ def test_command_fails_without_the_program(tmp_path):
     r = _bench(tmp_path, {"PYTHONPATH": ""})
     assert r.returncode != 0
     assert not r.stdout.strip().endswith("}")
+
+
+def test_check_holds_the_numbers_its_limits_name():
+    """A zero mean gradient gives no noise ratio; a number without a
+    limit is left out of the check."""
+    norms = {"w": np.ones(2)}
+    r = harness.Readings([1.0], [norms], [norms],
+                         {"sigma2": 2.0, "mean_norm2": 1.0}, norms, (0,))
+    zero = r._replace(stats={"sigma2": 0.0, "mean_norm2": 0.0})
+    numbers = harness.compare(zero, r)
+    assert numbers["stats"] == 1.0 and numbers["stats_ratio"] == np.inf
+    ok, check = harness.judge(numbers, {"loss": 0.1, "stats": 2.0})
+    assert ok and set(check) == {"loss", "stats"}
+    assert not harness.judge(numbers, {"loss": 0.1, "stats_ratio": 0.3})[0]

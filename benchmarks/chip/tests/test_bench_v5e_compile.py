@@ -50,7 +50,7 @@ def one_chip(no_persistent_cache):
 def cut():
     from repro import models
     cell = harness.load_cell(CELL)
-    cfg = harness.program_config(cell.config)
+    cfg = cell.family.program_config(cell.config)
     params = jax.eval_shape(
         lambda: models.init_params(cfg, jax.random.PRNGKey(0)))
     return cell, cfg, params

@@ -135,6 +135,12 @@ def family(config: dict, config_file: Path) -> ModuleType:
       tests' sizes, and optionally ``TINY_LIMITS``, the check's limits
       there (else ``tests/tiny.py``'s).
 
+    A per-layer metric's ``read(red, run)`` gets the trace's
+    ``Reduction`` and ``run``: the roles' programs (``roles``), the
+    step's model FLOPs and least bytes, the steps traced, the chip's
+    peaks, this module (``family``), its ``arch``, ``seq_len`` and the
+    tokens of one inner step (``step_tokens``).
+
     Raises, naming ``config_file``, where the key or the module is
     missing."""
     name = config.get("family")
@@ -645,6 +651,11 @@ def main(workload: str, seed: int, seconds: float, trace: bool,
     cache_dir = enable_compile_cache()
     import jax
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    # the trace's scopes are read from the programs' name stacks, which
+    # the cache's key leaves out by default: a program compiled from other
+    # source to the same operations would be found, with that source's
+    # names and lines
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     require_chips(jax, cell.chips)
     phases["jax_init"] = time.perf_counter() - t_process - phases["start"]
     dev = jax.devices()[0]
@@ -715,12 +726,14 @@ def main(workload: str, seed: int, seconds: float, trace: bool,
                                               accum=plan["accum_steps"] > 1),
                "steps_traced": H * M * red.rounds,
                "peak_flops": peak["bf16_flops_per_s"],
-               "peak_bytes_per_s": peak["hbm_bytes_per_s"]}
+               "peak_bytes_per_s": peak["hbm_bytes_per_s"],
+               "family": fam, "arch": arch, "seq_len": t["seq_len"],
+               "step_tokens": step_tokens}
         for m in cell.per_layer:
             v = metric_reader(m["name"])(red, run)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-        breakdown = {"device_ops": trace_reduce.top_programs(red),
+        breakdown = {"device_ops": trace_reduce.top_scopes(red),
                      "idle_gaps": trace_reduce.top_gaps(red)}
     else:
         values = {

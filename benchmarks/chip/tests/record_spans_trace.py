@@ -1,14 +1,19 @@
-"""Record ``testdata/round_spans.xplane.pb`` on a TPU: the plain cell cut
-to ``tiny.py``'s size, driven by the harness through its first round,
-one round that starts the profiler (``bench.warm``) and two traced
-rounds (``bench.round``), with the harness's spans and the program's.
+"""Record a small trace on a TPU for the reduction's tests: the plain
+cell cut to ``tiny.py``'s size, driven by the harness through its first
+round, one round that starts the profiler (``bench.warm``) and two
+traced rounds (``bench.round``), with the harness's spans and the
+program's.
 
-    python3 benchmarks/chip/tests/record_spans_trace.py [--out PATH]
+    python3 benchmarks/chip/tests/record_spans_trace.py [--scopes] [--out PATH]
+
+writes ``testdata/round_spans.xplane.pb``, or with ``--scopes``
+``testdata/scopes.xplane.pb``.
 
 The file keeps what ``trace_reduce`` reads, and the program's spans, and
 drops the rest (2 MB of compiled programs, host threads and event
-stats), which needs the ``XSpace`` protobuf that TensorFlow installs;
-the reduction of the kept trace equals that of the whole one, which the
+stats).  With ``--scopes`` it also keeps the stats of each op's metadata
+that the scope reduction reads (its program and name stack).  The
+reduction of the kept trace equals that of the whole one, which the
 script checks.
 """
 import argparse
@@ -23,19 +28,21 @@ CELL = "qwen3-0.6b.plain-1x1"
 SEED = 2 ** 33 + 12345
 #: the program's own spans (``repro.core.adloco.TrainerRound``)
 PROGRAM_PREFIX = "adloco."
+#: stats of an op's metadata that the scope reduction reads
+SCOPE_STATS = ("program_id", "tf_op")
 
 
-def trim(raw: bytes) -> bytes:
-    """The planes, lines and events ``trace_reduce`` reads, by name and
-    time alone: each TPU plane's ``XLA Modules`` and ``XLA Ops`` lines,
-    and the host plane's harness and program spans (``bench.*``,
-    ``adloco.*``)."""
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
+def trim(raw: bytes, op_stats=()) -> bytes:
+    """The planes, lines and events ``trace_reduce`` reads: each TPU
+    plane's ``XLA Modules`` and ``XLA Ops`` lines, with the stats named
+    in ``op_stats`` of each op's metadata, and the host plane's harness
+    and program spans (``bench.*``, ``adloco.*``)."""
     from benchmarks.chip import trace_reduce as T
+    from benchmarks.chip.xplane import messages
 
-    space = xplane_pb2.XSpace.FromString(raw)
-    out = xplane_pb2.XSpace()
+    XSpace = messages()["XSpace"]
+    space = XSpace.FromString(raw)
+    out = XSpace()
     for plane in space.planes:
         if plane.name == "/host:CPU":
             def keep(line, name):
@@ -46,6 +53,11 @@ def trim(raw: bytes) -> bytes:
         else:
             continue
         kept = out.planes.add(id=plane.id, name=plane.name)
+        stat_ids = {k for k, v in plane.stat_metadata.items()
+                    if v.name in op_stats}
+        for k in stat_ids:
+            kept.stat_metadata[k].id = k
+            kept.stat_metadata[k].name = plane.stat_metadata[k].name
         for line in plane.lines:
             names = plane.event_metadata
             events = [ev for ev in line.events
@@ -63,14 +75,21 @@ def trim(raw: bytes) -> bytes:
                 md = plane.event_metadata[ev.metadata_id]
                 kept.event_metadata[md.id].id = md.id
                 kept.event_metadata[md.id].name = md.name
+                if line.name == "XLA Ops" and not kept.event_metadata[
+                        md.id].stats:
+                    for st in md.stats:
+                        if st.metadata_id in stat_ids:
+                            kept.event_metadata[md.id].stats.add().CopyFrom(st)
     return out.SerializeToString()
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--out", default=str(HERE.parent / "testdata"
-                                         / "round_spans.xplane.pb"))
+    ap.add_argument("--scopes", action="store_true")
+    ap.add_argument("--out")
     args = ap.parse_args(argv)
+    out = Path(args.out or HERE.parent / "testdata" / (
+        "scopes.xplane.pb" if args.scopes else "round_spans.xplane.pb"))
     sys.path[0:1] = [str(ROOT), str(ROOT / "src")]
     import jax
 
@@ -78,6 +97,9 @@ def main(argv=None) -> int:
     from benchmarks.chip.tests.tiny import tiny_cell
 
     harness.require_chips(jax, 1)
+    # a program taken from the compilation cache keeps the name stacks of
+    # the source that compiled it: compile here, so they are this one's
+    jax.config.update("jax_enable_compilation_cache", False)
     cell = tiny_cell(CELL)
     prog = harness.build_and_first_round(cell, SEED, 64,
                                          harness.Norms(cell.family))
@@ -93,15 +115,18 @@ def main(argv=None) -> int:
         jax.profiler.stop_trace()
     whole = trace_reduce.find_xplane(log_dir)
     with open(whole, "rb") as f:
-        kept = trim(f.read())
-    with open(args.out, "wb") as f:
+        kept = trim(f.read(), SCOPE_STATS if args.scopes else ())
+    with open(out, "wb") as f:
         f.write(kept)
-    red = trace_reduce.reduce_trace(args.out)
-    if red != trace_reduce.reduce_trace(whole):
+    red = trace_reduce.reduce_trace(str(out))
+    want = trace_reduce.reduce_trace(whole)
+    if not args.scopes:      # the kept trace holds no op stats to read
+        red, want = (r._replace(scopes={}, innermost={}) for r in (red, want))
+    if red != want:
         raise SystemExit("record: the kept trace reduces differently")
     shutil.rmtree(log_dir, ignore_errors=True)
-    print(f"record: {args.out} rounds={red.rounds} "
-          f"programs={trace_reduce.top_programs(red, 5)}")
+    print(f"record: {out} rounds={red.rounds} "
+          f"scopes={trace_reduce.top_scopes(red, 8)}")
     return 0
 
 

@@ -1,6 +1,7 @@
 """Every cell's files load by name, a cell and a model family added as
 files alone are found, and BENCHMARK.json keeps to the shape the
 benchmark's contract gives."""
+import hashlib
 import json
 import re
 import shutil
@@ -27,7 +28,25 @@ def layout(a):
 
 def entry_axes(path):
     return 2 if path == "layers/gate" else dense.entry_axes(path)
+
+
+#: the step's named scopes that this family's own metric reads
+SCOPES = ("grad", "update")
 '''
+#: a reader of the family's own scopes: device ms a step under any of them
+TOY_METRIC = '''def read(red, run):
+    p = run["roles"].get("inner")
+    mine = set(run["family"].SCOPES)
+    ns = sum(v for (name, pid, scope), v in red.scopes.items()
+             if (name, pid) == (p.name, p.program_id) and scope in mine)
+    return ns / p.count / 1e6 if ns else None
+'''
+
+
+def _digests(root):
+    return {str(f.relative_to(root)): hashlib.sha256(f.read_bytes()).hexdigest()
+            for f in root.rglob("*")
+            if f.is_file() and "__pycache__" not in f.parts}
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -49,17 +68,21 @@ def test_cell_files_load_by_name(name):
 
 
 def test_cell_added_as_files_alone_is_found(tmp_path):
-    """A new configuration, model family, mix, cell and metric need new
-    files and new entries in BENCHMARK.json, and no edit of the harness.
-    The family ``toy`` is the dense block with a float32 final norm and
-    ``layers/gate`` compared by (layer, row)."""
+    """A new configuration, model family, mix, cell, its pins and
+    metrics need new files and new entries in BENCHMARK.json, and no
+    edit of any file there was.  The family ``toy`` is the dense block
+    with a float32 final norm and ``layers/gate`` compared by (layer,
+    row); its metric reads the trace's scopes that the family names."""
     import jax.numpy as jnp
 
+    from benchmarks.chip import trace_reduce
+    from benchmarks.chip.tests import test_bench_pins, test_bench_scopes
     from benchmarks.chip.weights import make_weights
 
     shutil.copytree(harness.ROOT / "benchmarks" / "chip",
                     tmp_path / "benchmarks" / "chip",
                     ignore=shutil.ignore_patterns("__pycache__"))
+    before = _digests(tmp_path)
     here = tmp_path / "benchmarks" / "chip"
     (here / "families" / "toy.py").write_text(TOY_FAMILY)
     config = json.loads((here / "configs" / "qwen3-0.6b.json").read_text())
@@ -73,6 +96,7 @@ def test_cell_added_as_files_alone_is_found(tmp_path):
                     "stats": 0.2, "outer": 0.5}))
     (here / "metrics" / "rounds_traced.py").write_text(
         "def read(red, run):\n    return float(red.rounds)\n")
+    (here / "metrics" / "toy_scopes_ms.py").write_text(TOY_METRIC)
     bench = json.loads(json.dumps(BENCH))
     bench["configs"].append(dict(bench["configs"][0], name="qwen3-0.6b-l4",
                                  file="benchmarks/chip/configs/"
@@ -85,19 +109,45 @@ def test_cell_added_as_files_alone_is_found(tmp_path):
         "name": "rounds_traced", "unit": "rounds", "better": "higher",
         "source": "device_trace", "layer": "whole round",
         "moves": "tokens_per_s", "workloads": ["qwen3-0.6b-l4.switch-2x4-h4"]})
+    bench["per_layer"].append({
+        "name": "toy_scopes_ms", "unit": "ms", "better": "lower",
+        "source": "device_trace", "layer": "inner step",
+        "moves": "tokens_per_s", "workloads": ["qwen3-0.6b-l4.switch-2x4-h4"]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    name = "qwen3-0.6b-l4.switch-2x4-h4"
+    test_bench_pins.write(name, tmp_path)
 
     cell = harness.load_cell("qwen3-0.6b-l4.switch-2x4-h4", root=tmp_path)
     assert cell.config["num_hidden_layers"] == 4
     assert cell.traffic["inner_steps"] == 4
     assert cell.family.__file__ == str(here / "families" / "toy.py")
     assert cell.family.program_config(cell.config).num_layers == 4
-    assert [m["name"] for m in cell.per_layer] == ["rounds_traced"]
+    assert [m["name"] for m in cell.per_layer] == ["rounds_traced",
+                                                   "toy_scopes_ms"]
     read = harness.metric_reader("rounds_traced", root=tmp_path)
 
     class Red:
         rounds = 3
     assert read(Red, {}) == 3.0
+    red = trace_reduce.reduce_trace(test_bench_scopes.TRACE)
+    roles = test_bench_scopes.harness_roles(red)
+    p = roles["inner"]
+    want = sum(red.scopes[(p.name, p.program_id, s)]
+               for s in cell.family.SCOPES) / p.count / 1e6
+    got = harness.metric_reader("toy_scopes_ms", root=tmp_path)(
+        red, {"roles": roles, "family": cell.family})
+    assert got == pytest.approx(want, rel=1e-12) and got > 0
+    test_bench_pins.check_pinned(name, tmp_path)
+    after = _digests(tmp_path)
+    assert {k: after[k] for k in before} == before
+    assert set(after) - set(before) == {
+        "BENCHMARK.json", "benchmarks/chip/families/toy.py",
+        "benchmarks/chip/configs/qwen3-0.6b-l4.json",
+        "benchmarks/chip/traffic/switch-2x4-h4.json",
+        f"benchmarks/chip/limits/{name}.json",
+        f"benchmarks/chip/testdata/pins/{name}.json",
+        "benchmarks/chip/metrics/rounds_traced.py",
+        "benchmarks/chip/metrics/toy_scopes_ms.py"}
 
     small = tiny_cell("qwen3-0.6b-l4.switch-2x4-h4", root=tmp_path)
     fam = small.family

@@ -4,10 +4,13 @@ loss, the batch statistics, and each per-entry norm of the first
 gradients, the changes and the outer step), to 6 significant digits.
 A change that moves the benchmark's weights or its reference fails here.
 
-    PYTHONPATH=src:. JAX_PLATFORMS=cpu python3 -m \\
-        benchmarks.chip.tests.test_bench_pins
+Each cell's pins are a file of their own, ``testdata/pins/<cell>.json``,
+so a cell is added with its pins as a new file.
 
-writes the pins anew (``testdata/first_round_pins.json``).
+    PYTHONPATH=src:. JAX_PLATFORMS=cpu python3 -m \\
+        benchmarks.chip.tests.test_bench_pins <cell>
+
+writes that cell's file, and no other.
 """
 import hashlib
 import json
@@ -20,11 +23,15 @@ import pytest
 from benchmarks.chip import harness
 from benchmarks.chip.tests.tiny import tiny_cell
 
-PINS = Path(__file__).resolve().parents[1] / "testdata" / \
-    "first_round_pins.json"
 CELLS = [w["name"] for w in json.loads(
     (harness.ROOT / "BENCHMARK.json").read_text())["workloads"]]
 SEED = 2 ** 33 + 12345
+WRITE = "PYTHONPATH=src:. JAX_PLATFORMS=cpu python3 -m " \
+        "benchmarks.chip.tests.test_bench_pins"
+
+
+def pins_file(name: str, root: Path = harness.ROOT) -> Path:
+    return root / "benchmarks" / "chip" / "testdata" / "pins" / f"{name}.json"
 
 
 def _sig(values) -> list:
@@ -70,18 +77,40 @@ def first_round(cell) -> dict:
             "outer": _entries(r.outer)}
 
 
-def pins(name: str) -> dict:
-    cell = tiny_cell(name)
+def pins(name: str, root: Path = harness.ROOT) -> dict:
+    cell = tiny_cell(name, root)
     return {"weights_sha256": weights_digest(cell),
             "first_round": first_round(cell)}
 
 
+def check_pinned(name: str, root: Path = harness.ROOT) -> None:
+    path = pins_file(name, root)
+    if not path.is_file():
+        pytest.fail(f"no pins for the cell {name!r} ({path}); write them "
+                    f"with: {WRITE} {name}")
+    assert pins(name, root) == json.loads(path.read_text())
+
+
 @pytest.mark.parametrize("name", CELLS)
 def test_weights_and_reference_first_round_are_pinned(name):
-    want = json.loads(PINS.read_text())[name]
-    assert pins(name) == want
+    check_pinned(name)
+
+
+def test_a_cell_without_its_pins_fails_naming_the_command(tmp_path):
+    with pytest.raises(pytest.fail.Exception,
+                       match=f"{WRITE} qwen3-0.6b.absent"):
+        check_pinned("qwen3-0.6b.absent", tmp_path)
+
+
+def write(name: str, root: Path = harness.ROOT) -> Path:
+    """Write the pins of the cell ``name`` alone."""
+    path = pins_file(name, root)
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(json.dumps(pins(name, root), indent=1) + "\n")
+    return path
 
 
 if __name__ == "__main__":
-    PINS.write_text(json.dumps({n: pins(n) for n in CELLS}, indent=1) + "\n")
-    sys.exit(0)
+    if len(sys.argv) != 2:
+        raise SystemExit(f"usage: {WRITE} <cell>")
+    print(write(sys.argv[1]))

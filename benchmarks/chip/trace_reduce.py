@@ -22,7 +22,14 @@ of the trace.
   can lead the host's by a millisecond or so, which would cut a round's
   first program off at the window's edge;
 * idle gaps: the stretches of the traced window in which no op ran, each
-  attributed to the innermost harness span that covers its midpoint.
+  attributed to the innermost harness span that covers its midpoint;
+* scopes: device time of each program's operations by scope, over the
+  same whole trace as programs.  An op's named scopes come from its name
+  stack, the ``tf_op`` stat of its event metadata on the device plane
+  (XLA's ``op_name``: ``jit(f)/grad/transpose(jvp(attention))/dot_general``),
+  and the program it ran in from the ``program_id`` stat there.  Only
+  ops that contain no other op (not a ``while`` around its body) are
+  summed, and an op counts once under each scope on its stack.
 """
 from __future__ import annotations
 
@@ -30,7 +37,7 @@ import glob
 import os
 import re
 from collections import defaultdict
-from typing import Dict, List, NamedTuple, Optional, Tuple
+from typing import Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 MODULE_NAME = re.compile(r"^(?P<name>.*)\((?P<id>-?\d+)\)$")
@@ -52,6 +59,11 @@ class Reduction(NamedTuple):
     program_rounds: int         # whole rounds the programs were counted over
     programs: Dict[Tuple[str, str], Program]
     gaps: List[Tuple[str, float]]   # (covering span, ns), longest first
+    #: (program name, program id, scope) -> device ns of the leaf ops
+    #: under that scope, every execution in the trace
+    scopes: Dict[Tuple[str, str, str], float] = {}
+    #: (program name, program id, innermost scope or "") -> device ns
+    innermost: Dict[Tuple[str, str, str], float] = {}
 
     @property
     def idle_share(self) -> float:
@@ -130,6 +142,15 @@ def reduce_trace(path: str, *, span_prefix: str = SPAN_PREFIX) -> Reduction:
             if e > s:
                 gaps.append((_covering(spans, (s + e) / 2), e - s))
     gaps.sort(key=lambda g: -g[1])
+    scopes: Dict[Tuple[str, str, str], float] = defaultdict(float)
+    innermost: Dict[Tuple[str, str, str], float] = defaultdict(float)
+    names = {k[1]: k[0] for k in programs}
+    for op in leaf_ops(path):
+        name = names.get(op.program_id, "")
+        for scope in op.scopes:
+            scopes[(name, op.program_id, scope)] += op.ns
+        inner = op.scopes[-1] if op.scopes else ""
+        innermost[(name, op.program_id, inner)] += op.ns
     return Reduction(
         window_ns=hi - lo,
         busy_ns=sum(busy_per_chip) / len(busy_per_chip),
@@ -139,7 +160,119 @@ def reduce_trace(path: str, *, span_prefix: str = SPAN_PREFIX) -> Reduction:
                                        span_prefix + "warm")),
         programs={k: Program(k[0], k[1], int(c), float(ns))
                   for k, (c, ns) in programs.items()},
-        gaps=gaps)
+        gaps=gaps, scopes=dict(scopes), innermost=dict(innermost))
+
+
+# ---------------------------------------------------------------- scopes
+#: segments of a name stack that wrap code and name no scope of the user
+WRAPPERS = frozenset({"while", "body", "cond", "closed_call", "core_call",
+                      "checkpoint", "remat", "rematted_computation", "scan",
+                      "pjit", "jit", "custom_jvp_call", "custom_vjp_call",
+                      "shard_map", "xla_call", "xla_pmap"})
+#: transforms whose parentheses hold the scopes they were applied to
+TRANSFORMS = frozenset({"jvp", "transpose", "vmap", "batching", "vjp",
+                        "linearize", "remat", "checkpoint"})
+SCOPE = re.compile(r"^[A-Za-z_][A-Za-z0-9_.-]*$")
+BRANCH = re.compile(r"^branch_\d+")
+
+
+def _split(stack: str) -> List[str]:
+    """``stack`` split at each ``/`` outside parentheses."""
+    out, depth, start = [], 0, 0
+    for i, ch in enumerate(stack):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            out.append(stack[start:i])
+            start = i + 1
+    out.append(stack[start:])
+    return [seg for seg in out if seg]
+
+
+def _scopes_in(segments: Iterable[str]) -> List[str]:
+    out: List[str] = []
+    for seg in segments:
+        head, paren, inner = seg.partition("(")
+        if paren:
+            if head in TRANSFORMS and inner.endswith(")"):
+                out.extend(_scopes_in(_split(inner[:-1])))
+        elif (SCOPE.match(seg) and seg not in WRAPPERS
+              and not BRANCH.match(seg)):
+            out.append(seg)
+    return out
+
+
+def named_scopes(op_name: str) -> Tuple[str, ...]:
+    """The user's named scopes on an op's name stack, outermost first,
+    each once: ``jit(f)/grad/transpose(jvp(attention))/while/body/dot``
+    gives ``("grad", "attention")``.  The last segment, the operation
+    itself, and the ``:type`` the profiler appends are no scope."""
+    stack = op_name.rpartition(":")[0] if ":" in op_name else op_name
+    segments = _split(stack)[:-1]
+    return tuple(dict.fromkeys(_scopes_in(segments)))
+
+
+class Op(NamedTuple):
+    program_id: str
+    ns: float                   # every execution in the trace together
+    scopes: Tuple[str, ...]     # named, outermost first
+
+
+def leaf_ops(path: str) -> List[Op]:
+    """Device time of the ops of each TPU plane that contain no other op,
+    one entry for each op's metadata (every execution of that op in the
+    trace), with its program and named scopes."""
+    from benchmarks.chip.xplane import read_space, stat_value
+
+    ops: Dict[Tuple[int, int], float] = defaultdict(float)
+    meta: Dict[Tuple[int, int], Tuple[str, str]] = {}
+    for i, plane in enumerate(read_space(path).planes):
+        if not DEVICE_PLANE.match(plane.name):
+            continue
+        stat_names = {k: v.name for k, v in plane.stat_metadata.items()}
+        for line in plane.lines:
+            if line.name != "XLA Ops":
+                continue
+            t0 = line.timestamp_ns * 1000
+            evs = sorted(((t0 + ev.offset_ps, t0 + ev.offset_ps
+                           + ev.duration_ps, ev.metadata_id)
+                          for ev in line.events),
+                         key=lambda e: (e[0], -e[1]))
+            for s, e, mid in _leaves(evs):
+                ops[(i, mid)] += (e - s) / 1e3
+                if (i, mid) not in meta:
+                    stats = {stat_names.get(st.metadata_id):
+                             stat_value(st, stat_names)
+                             for st in plane.event_metadata[mid].stats}
+                    meta[(i, mid)] = (str(stats.get("program_id", "")),
+                                      stats.get("tf_op") or "")
+    return [Op(meta[key][0], ns, named_scopes(meta[key][1]))
+            for key, ns in ops.items()]
+
+
+def _leaves(evs):
+    """The events, sorted by start (and longest first), that hold no
+    other event of the list inside their span."""
+    holds = [False] * len(evs)
+    open_: List[int] = []
+    for i, (s, e, _) in enumerate(evs):
+        while open_ and evs[open_[-1]][1] <= s:
+            open_.pop()
+        if open_ and e <= evs[open_[-1]][1] and (s, e) != evs[open_[-1]][:2]:
+            holds[open_[-1]] = True
+        open_.append(i)
+    return [ev for ev, h in zip(evs, holds) if not h]
+
+
+def scope_ms(red: Reduction, program, scope: str) -> Optional[float]:
+    """Device ms under ``scope`` in one execution of ``program`` (a
+    ``Program``), or None where the trace holds none."""
+    if program is None or not program.count:
+        return None
+    ns = red.scopes.get((program.name, program.program_id, scope))
+    return ns / program.count / 1e6 if ns else None
 
 
 def _covering(spans, t: float) -> str:
@@ -174,9 +307,12 @@ def assign_roles(red: Reduction, expected: Dict[str, Tuple[str, int]]
     return roles
 
 
-def top_programs(red: Reduction, n: int = 10) -> List[List]:
-    progs = sorted(red.programs.values(), key=lambda p: -p.device_ns)[:n]
-    return [[f"{p.name}({p.program_id})", p.device_ns / 1e9] for p in progs]
+def top_scopes(red: Reduction, n: int = 10) -> List[List]:
+    """The ``n`` largest (program, innermost scope) entries, in seconds
+    over the trace; ``-`` where an op is under no scope."""
+    top = sorted(red.innermost.items(), key=lambda kv: -kv[1])[:n]
+    return [[f"{name}({pid}) {scope or '-'}", ns / 1e9]
+            for (name, pid, scope), ns in top]
 
 
 def top_gaps(red: Reduction, n: int = 10) -> List[List]:

@@ -40,8 +40,9 @@ Execution backends
 ``SimBackend``
     Prices every collective analytically (``comms.
     hierarchical_allreduce_time`` under a ``Topology``, the flat ring
-    otherwise) and executes the outer reduction as the in-process
-    ``jnp.stack`` it always was.  The default when ``run_cluster`` gets
+    otherwise) and executes the outer reduction in-process: the
+    workers' params go straight into the jitted outer step, which
+    averages them.  The default when ``run_cluster`` gets
     a ``network=``; bit-identical to the pre-backend runtime (the
     golden-trace digests pin it).
 ``JaxProcessBackend``
@@ -75,14 +76,15 @@ it at two different simulated instants:
   return without waiting for the result: ``JaxProcessBackend`` enqueues
   the jitted ``pmean`` chain via JAX async dispatch (no
   ``block_until_ready``), so the wire works while the caller keeps
-  computing; ``SimBackend`` evaluates eagerly (pure in-process
-  arithmetic — the handle just carries the finished result).
-* ``wait_outer(handle) -> (stacked, stats_total_or_None)`` is called at
+  computing; ``SimBackend`` does no work (the handle just carries the
+  workers' params, which the outer step averages).
+* ``wait_outer(handle) -> (workers, stats_total_or_None)`` is called at
   the collective's *arrival* (the priced completion event).  It blocks
   until the result is ready, records the true in-flight window
   (dispatch -> ready) as a ``real``-clock span, and hands back the
-  reduced params plus the SUM-reduced phase-1 stats vector when one was
-  fused in.
+  sequence of param-shaped pytrees the outer step averages (the sim's
+  workers, or a 1-tuple of the reduced mean) plus the SUM-reduced
+  phase-1 stats vector when one was fused in.
 
 Dispatch order is part of the lockstep contract: every process reaches
 every ``dispatch_outer`` in the same order with the same shapes
@@ -103,7 +105,7 @@ not pay a standalone gradient-order stats collective: the phase-1
 rides the next outer dispatch as ONE fused collective — traced and
 priced as kind ``"piggyback"`` with ``payload_bytes = params_bytes +
 stats_payload_bytes``, counted in ``num_stats_syncs``.  On
-``JaxProcessBackend`` the fused tree is ``{"params": <stacked pytree>,
+``JaxProcessBackend`` the fused tree is ``{"params": <(1, ...) pytree>,
 "stats": <(1, n+1) float32>}`` reduced by the same ``pmean`` chain; the
 phase-2 five scalar moments chain onto the same in-flight window: the
 dispatch derives the global mean gradient from the enqueued phase-1

@@ -9,9 +9,9 @@ drives either
     The default.  Collectives are *priced* analytically (delegating to
     the wrapped :class:`~repro.cluster.network.NetworkModel` /
     :class:`~repro.cluster.network.Topology`) and *executed* locally —
-    the outer reduction is the in-process ``jnp.stack`` the runtime has
-    always done.  Behavior is bit-identical to the pre-backend runtime;
-    the golden-trace suite pins that.
+    the workers' params go straight into the jitted outer step, which
+    averages them.  Behavior is bit-identical to the pre-backend
+    runtime; the golden-trace suite pins that.
 :class:`JaxProcessBackend`
     One OS process per worker via ``jax.distributed.initialize`` (see
     ``repro.cluster.launch_mp``): every process runs the *same*
@@ -76,11 +76,11 @@ class CollectiveBackend:
 
     Pricing methods mirror the network-model interface so the runtime
     can stay network-agnostic; execution methods carry the actual
-    parameter movement.  ``outer_reduce`` must return a pytree whose
-    leaves have a leading *worker* axis ready for
-    ``repro.core.diloco.make_outer_step``'s mean — either the full
-    (M, ...) stack (sim) or an already-reduced (1, ...) mean (real
-    collectives).
+    parameter movement.  ``outer_reduce`` must return a sequence of
+    param-shaped pytrees for ``repro.core.diloco.make_outer_step`` to
+    average inside its program — either the M workers' params
+    themselves (sim) or a 1-tuple holding the already-reduced mean
+    (real collectives).  No worker-stacked array is built.
     """
 
     name = "abstract"
@@ -136,7 +136,8 @@ class CollectiveBackend:
 
     def outer_reduce(self, worker_params: List[Any]) -> Any:
         """List of per-worker pytrees (None for workers that live on
-        other processes) -> pytree with a leading worker axis."""
+        other processes) -> sequence of param-shaped pytrees whose mean
+        is the workers' mean."""
         raise NotImplementedError
 
     # ------------------------------------------- dispatch/handle split
@@ -177,9 +178,10 @@ class CollectiveBackend:
 
     def wait_outer(self, handle) -> tuple:
         """Block on a :meth:`dispatch_outer` handle.  Returns
-        ``(stacked, stats_total)``: the worker-stacked (or already
-        reduced ``(1, ...)``) params pytree, and the SUM-reduced phase-1
-        vector (None when no ``stats_vec`` was fused)."""
+        ``(workers, stats_total)``: the sequence of param-shaped
+        pytrees :meth:`outer_reduce` would return (the workers' params,
+        or a 1-tuple of their already-reduced mean), and the SUM-reduced
+        phase-1 vector (None when no ``stats_vec`` was fused)."""
         raise NotImplementedError
 
     def note_real_compute(self, t0: float, dt: float, *,
@@ -284,12 +286,12 @@ class SimBackend(CollectiveBackend):
         if any(wp is None for wp in worker_params):
             raise ValueError("SimBackend executes every worker in-process;"
                              " got a partial worker set")
-        return jax.tree.map(lambda *xs: jnp.stack(xs), *worker_params)
+        return tuple(worker_params)
 
     def dispatch_outer(self, worker_params, *, stats_vec=None,
                        phase2=None, tid=None, template=None):
-        # The sim's "wire" is the priced clock, not real time: the stack
-        # happens eagerly at dispatch and the handle is just the result.
+        # The sim's "wire" is the priced clock, not real time: the handle
+        # is just the workers' params, averaged inside the outer step.
         # A fused stats_vec reduces over the one process = identity sum;
         # phase2/tid/template are multi-process concerns (the sim holds
         # every worker and every trainer in-process).
@@ -608,9 +610,9 @@ class JaxProcessBackend(CollectiveBackend):
         host = self._execute(tree)
         self._last_measured = time.perf_counter() - t0
         self._record_real("outer", t0, self._last_measured)
-        # every shard now holds the global mean: a (1, ...) worker axis
-        # that make_outer_step's mean passes through unchanged
-        return host
+        # every shard now holds the global mean; drop the collective's
+        # (1, ...) axis: make_outer_step's mean of one tree is that tree
+        return (jax.tree.map(lambda x: x[0], host),)
 
     def dispatch_outer(self, worker_params, *, stats_vec=None,
                        phase2=None, tid=None, template=None):
@@ -694,11 +696,14 @@ class JaxProcessBackend(CollectiveBackend):
         # any chained phase-2 moments collective)
         self._record_real("piggyback" if handle["fused"] else "outer",
                           t0, dt)
+        # drop the collective's (1, ...) axis, as outer_reduce does
+        mean = (jax.tree.map(lambda x: x[0],
+                             host["params"] if handle["fused"] else host),)
         if handle["fused"]:
             # same mean -> sum rescale for the fused phase-1 vector
             stats_total = host["stats"][0] * jnp.float32(self.num_processes)
-            return host["params"], stats_total
-        return host, None
+            return mean, stats_total
+        return mean, None
 
     def pop_phase2_total(self):
         v, self._last_phase2 = self._last_phase2, None

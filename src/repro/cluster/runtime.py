@@ -68,7 +68,7 @@ Nonblocking collectives: the runtime *dispatches* every outer sync at
 its launch point (:meth:`CollectiveBackend.dispatch_outer`) and waits
 for the result only at the arrival event
 (:meth:`CollectiveBackend.wait_outer`).  Under the sim backend that is
-a semantic no-op (the stack is eager), but on
+a semantic no-op (the handle is the workers' params), but on
 ``JaxProcessBackend`` the jitted collective is enqueued without a
 ready-wait, so the next round's inner compute — which the async
 schedule runs between launch and arrival — executes while the wire
@@ -611,7 +611,7 @@ class _Sim:
         self.report.sim_time = max(self.report.sim_time, now)
         rt.inflight = False
         rt.comm_ev = None
-        stacked, stats_tot = self.backend.wait_outer(ev["handle"])
+        workers, stats_tot = self.backend.wait_outer(ev["handle"])
         # measured staleness in rounds: rounds already folded since the
         # snapshot, plus the in-flight round that will rebase onto this
         # update at its boundary (async steady state: 1; sync: 0)
@@ -619,7 +619,7 @@ class _Sim:
         if self.policy != "sync" and rt.round < rt.target:
             delay += 1.0
         self.rnd.outer(rt.tr, ev["snapshot"], x_prev=ev["x_prev"],
-                       reduce=lambda _wp: stacked, delay=delay)
+                       reduce=lambda _wp: workers, delay=delay)
         measured = self.backend.pop_measured()
         if measured is not None:
             self.report.real_comm_time += measured

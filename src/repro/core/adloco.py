@@ -474,23 +474,21 @@ class TrainerRound:
         ``x_prev`` defaults to the trainer's current synced params; the
         async cluster policy passes the anchor captured at launch time
         (delayed application).  ``reduce`` maps the per-worker params
-        list to the worker-stacked pytree ``make_outer_step`` averages —
-        the default is the in-process ``jnp.stack``; execution backends
-        substitute a real cross-process collective that returns the
-        already-reduced (1, ...) mean.  ``delay`` is the measured
+        list to the sequence of param-shaped pytrees ``make_outer_step``
+        averages — by default the workers' params themselves, passed
+        straight into the jitted step; execution backends substitute a
+        real cross-process collective that returns the already-reduced
+        mean as a 1-tuple.  ``delay`` is the measured
         staleness in rounds (how many inner rounds folded between the
         snapshot and this application); with ``delay_compensation`` on
         it damps the momentum contribution accordingly, otherwise it is
         ignored by the jitted step."""
         with TraceAnnotation("adloco.outer.stack"):
-            if reduce is None:
-                stacked = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                       *worker_params)
-            else:
-                stacked = reduce(worker_params)
+            workers = (tuple(worker_params) if reduce is None
+                       else reduce(worker_params))
         tr.params, tr.outer_opt_state = self.outer_step(
             x_prev if x_prev is not None else tr.params,
-            stacked, tr.outer_opt_state, float(delay))
+            workers, tr.outer_opt_state, float(delay))
         if comms is not None:
             comms.record("outer", participants=len(worker_params),
                          payload_bytes=param_bytes(tr.params), step=step)

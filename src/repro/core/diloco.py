@@ -8,7 +8,8 @@ batching doesn't thrash XLA.
 """
 from __future__ import annotations
 
-from functools import partial
+import operator
+from functools import reduce
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -78,25 +79,32 @@ def make_inner_step(loss_fn: Callable, inner_opt: optim.Optimizer,
 
 def make_outer_step(outer_opt: optim.Optimizer, *,
                     delay_aware: bool = False):
-    """jitted fn(x_prev, worker_params [stacked leading M axis],
-    outer_state, delay) -> (x_new, outer_state).
+    """jitted fn(x_prev, worker_params, outer_state, delay)
+    -> (x_new, outer_state).
 
-    Pseudo-gradient Δ = x_prev − mean_m(x_m)  (paper Alg 3 line 42); in a
-    multi-host deployment the mean is the inter-worker all-reduce this
-    framework meters as communication.  ``delay`` is the measured
-    staleness (rounds folded between snapshot and application, f32
-    scalar): with ``delay_aware=True`` it is forwarded to the
+    ``worker_params`` is a sequence of M param-shaped pytrees: the
+    workers' params, or a backend's already-reduced mean as a 1-tuple.
+    Pseudo-gradient Δ = x_prev − (Σ_m x_m) / M in f32 (paper Alg 3 line
+    42), formed inside the program, so each worker's leaf is read
+    straight into the delta and the update and no worker-stacked copy
+    exists; in a multi-host deployment the mean is the inter-worker
+    all-reduce this framework meters as communication.  ``delay`` is the
+    measured staleness (rounds folded between snapshot and application,
+    f32 scalar): with ``delay_aware=True`` it is forwarded to the
     optimizer's ``update`` (``optim.delay_compensated_nesterov``), which
     scales the momentum contribution accordingly; otherwise it is
     ignored, keeping the plain path bit-identical to the legacy step.
     """
 
+    def worker_mean(*ws):
+        return reduce(operator.add,
+                      [w.astype(jnp.float32) for w in ws]) / len(ws)
+
     def outer_step(x_prev, worker_params, outer_state, delay=0.0):
         with jax.named_scope("grad"):
             delta = jax.tree.map(
-                lambda xp, w: xp.astype(jnp.float32)
-                - jnp.mean(w.astype(jnp.float32), axis=0),
-                x_prev, worker_params)
+                lambda xp, *ws: xp.astype(jnp.float32) - worker_mean(*ws),
+                x_prev, *worker_params)
         with jax.named_scope("update"):
             if delay_aware:
                 updates, outer_state = outer_opt.update(

@@ -231,15 +231,43 @@ def test_accum_equals_big_batch_gradient():
                                rtol=1e-5)
 
 
-def test_outer_step_moves_toward_worker_mean():
-    """With lr_outer=1, momentum=0: x_new = mean(workers)."""
+def _stacked_outer_step(opt):
+    """The outer step as it was when its workers came stacked on a
+    leading axis: the reference for the list form."""
+    def step(x_prev, stacked, state):
+        delta = jax.tree.map(
+            lambda xp, w: xp.astype(jnp.float32)
+            - jnp.mean(w.astype(jnp.float32), axis=0), x_prev, stacked)
+        updates, state = opt.update(delta, state, x_prev)
+        return optim.apply_updates(x_prev, updates), state
+    return jax.jit(step)
+
+
+@pytest.mark.parametrize("M", [1, 2, 4])
+def test_outer_step_moves_toward_worker_mean(M):
+    """With lr_outer=1, momentum=0: x_new = mean(workers), from the M
+    workers' params passed as a tuple.  M=1 is a backend's reduced mean,
+    passed through unchanged, and M=2 is bit-equal to the step over a
+    stacked worker axis; M=4 sums in another order, within 1e-6."""
     opt = optim.sgd(1.0)
     outer = make_outer_step(opt)
-    x_prev = {"x": jnp.zeros(4)}
-    workers = {"x": jnp.asarray([[1.0, 2, 3, 4], [3.0, 2, 1, 0]])}
+    rng = np.random.default_rng(M)
+    x_prev = {"x": jnp.asarray(rng.normal(size=(3, 5)), jnp.float32),
+              "b": jnp.asarray(rng.normal(size=7), jnp.float32)}
+    workers = tuple(jax.tree.map(
+        lambda x: x + jnp.asarray(rng.normal(size=x.shape), jnp.float32),
+        x_prev) for _ in range(M))
     x_new, _ = outer(x_prev, workers, opt.init(x_prev))
-    np.testing.assert_allclose(np.asarray(x_new["x"]), [2, 2, 2, 2],
-                               rtol=1e-6)
+    stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *workers)
+    x_old, _ = _stacked_outer_step(opt)(x_prev, stacked, opt.init(x_prev))
+    for k in x_prev:
+        got, old = np.asarray(x_new[k]), np.asarray(x_old[k])
+        mean = np.mean([np.asarray(w[k], np.float64) for w in workers], 0)
+        np.testing.assert_allclose(got, mean, rtol=1e-6, atol=1e-6)
+        if M <= 2:
+            np.testing.assert_array_equal(got, old)
+        else:
+            np.testing.assert_allclose(got, old, rtol=1e-6, atol=1e-6)
 
 
 def test_step_cache_buckets():

@@ -148,6 +148,18 @@ def test_round_spans(case, tmp_path):
         return any(p == parent and ps <= s and e <= pe
                    for p, ps, pe in spans)
 
+    # the outer step takes the workers' params as they are: inside
+    # adloco.outer the host dispatches that one program and no eager
+    # stack (broadcast_in_dim, concatenate) of them; programs nested in
+    # another (trace-time constants of a first call) are not dispatches
+    pjit = [(n, s, e) for n, s, e in spans if n.startswith("PjitFunction(")]
+    outer_programs = Counter(
+        n for n, s, e in pjit if inside(s, e, "adloco.outer")
+        and not any((ps, pe) != (s, e) and ps <= s and e <= pe
+                    for _, ps, pe in pjit))
+    assert outer_programs == {"PjitFunction(outer_step)":
+                              want["adloco.outer"]}, outer_programs
+
     parent = dict(PARENT)
     if "adloco.round" in want:
         parent.update({"adloco.inner": "adloco.round",
